@@ -55,7 +55,7 @@ import numpy as np
 
 from benchmarks.common import Timer, emit
 from repro.core.matchmaker import (
-    HAVE_JAX, MatchProblem, NumpyMatchmaker, make_matchmaker,
+    MatchProblem, NumpyMatchmaker, make_matchmaker,
 )
 
 TIERS = {
@@ -113,8 +113,7 @@ def run_preview_replay(n_jobs: int = 2_000, duration_s: float = 14_400.0,
 
     out: dict = {"jobs": n_jobs, "duration_s": duration_s, "seed": seed,
                  "negotiation_batch": batch}
-    backends = ("numpy",) + (("jax",) if HAVE_JAX else ())
-    for mm in backends:
+    for mm in ("numpy", "jax"):
         trace = diurnal_day(n_jobs, seed=seed, duration_s=duration_s)
         # fusion-friendly cadence: negotiations fire every 20s INSIDE a
         # 60s tick/reconcile/metrics grid, so the [20,40] windows carry
@@ -151,7 +150,7 @@ def run_preview_replay(n_jobs: int = 2_000, duration_s: float = 14_400.0,
                 round(fallbacks.get("single_cycle", 0) / flushes, 3)
                 if flushes else None),
         }
-    if "jax" in out and out["numpy"]["preview_s"] > 0:
+    if out["numpy"]["preview_s"] > 0:
         out["preview_ratio"] = round(
             out["jax"]["preview_s"] / out["numpy"]["preview_s"], 3)
     return out
@@ -222,10 +221,6 @@ def run_e2e(tier: str, repeats: int, jax_mm, numpy_mm) -> dict:
         (percycle(numpy_mm) for _ in range(repeats)), key=lambda r: r[0])
     row["numpy_s"] = round(np_s, 4)
     row["claimed"] = np_claimed
-    if jax_mm is None:
-        row.update(jax_s=None, fused_s=None, fused_ratio=None,
-                   e2e_identical=None, fused_batches=0)
-        return row
     percycle(jax_mm)                                  # warmup: jit trace
     fused(jax_mm)
     jx_s, jx_claimed, jx_map = min(
@@ -246,8 +241,8 @@ def run(echo: bool = True, tiers=("10k", "100k"), repeats: int = 5,
         e2e_tiers=("10k",), e2e_repeats: int = 3,
         preview_jobs: int | None = 2_000):
     ref = NumpyMatchmaker()
-    jaxmm = make_matchmaker("jax") if HAVE_JAX else None
-    out = {"have_jax": HAVE_JAX, "tiers": {}, "e2e": {}}
+    jaxmm = make_matchmaker("jax")
+    out = {"tiers": {}, "e2e": {}}
     with Timer() as total:
         for tier in tiers:
             spec = TIERS[tier]
@@ -256,17 +251,12 @@ def run(echo: bool = True, tiers=("10k", "100k"), repeats: int = 5,
             plan_ref = ref.match(p)
             row["claimed"] = plan_ref.claimed
             row["numpy_s"] = best_of(lambda: ref.match(p), repeats)
-            if jaxmm is not None:
-                plan_jax = jaxmm.match(p)          # warmup: jit trace
-                row["identical"] = bool(
-                    np.array_equal(plan_ref.takes, plan_jax.takes)
-                    and np.allclose(plan_ref.free_after,
-                                    plan_jax.free_after))
-                row["jax_s"] = best_of(lambda: jaxmm.match(p), repeats)
-                row["ratio"] = round(row["numpy_s"] / row["jax_s"], 2)
-            else:
-                row["identical"] = None
-                row["jax_s"] = row["ratio"] = None
+            plan_jax = jaxmm.match(p)              # warmup: jit trace
+            row["identical"] = bool(
+                np.array_equal(plan_ref.takes, plan_jax.takes)
+                and np.allclose(plan_ref.free_after, plan_jax.free_after))
+            row["jax_s"] = best_of(lambda: jaxmm.match(p), repeats)
+            row["ratio"] = round(row["numpy_s"] / row["jax_s"], 2)
             out["tiers"][tier] = row
         for tier in e2e_tiers:
             out["e2e"][tier] = run_e2e(tier, e2e_repeats, jaxmm, ref)
@@ -323,8 +313,7 @@ def main(argv=None) -> int:
         ratio = pr.get("preview_ratio")
         if ratio is None:
             print("[bench] FAIL: --preview-max-ratio given but the "
-                  "preview replay tier did not run with jax",
-                  file=sys.stderr)
+                  "preview replay tier did not run", file=sys.stderr)
             rc = 1
         elif ratio > args.preview_max_ratio:
             print(f"[bench] FAIL: jax preview wall {pr['jax']['preview_s']}s"
@@ -363,11 +352,7 @@ def main(argv=None) -> int:
             rc = 1
     if args.e2e_min_ratio is not None and e2e_tiers:
         top = out["e2e"][e2e_tiers[0]]
-        if top["fused_ratio"] is None:
-            print("[bench] FAIL: --e2e-min-ratio given but jax unavailable",
-                  file=sys.stderr)
-            rc = 1
-        elif top["fused_batches"] < 1:
+        if top["fused_batches"] < 1:
             print("[bench] FAIL: fused path never engaged "
                   f"(fallbacks={top['staged_fallbacks']})", file=sys.stderr)
             rc = 1
@@ -377,16 +362,10 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             rc = 1
     top = out["tiers"][tiers[-1]]
-    if args.min_ratio is not None:
-        if top["ratio"] is None:
-            print("[bench] FAIL: --min-ratio given but jax unavailable",
-                  file=sys.stderr)
-            rc = 1
-        elif top["ratio"] < args.min_ratio:
-            print(f"[bench] FAIL: jax speedup {top['ratio']}x < "
-                  f"{args.min_ratio}x at tier {tiers[-1]}",
-                  file=sys.stderr)
-            rc = 1
+    if args.min_ratio is not None and top["ratio"] < args.min_ratio:
+        print(f"[bench] FAIL: jax speedup {top['ratio']}x < "
+              f"{args.min_ratio}x at tier {tiers[-1]}", file=sys.stderr)
+        rc = 1
     if args.budget_s is not None and out["wall_s"] > args.budget_s:
         print(f"[bench] FAIL: wall {out['wall_s']}s > budget "
               f"{args.budget_s}s", file=sys.stderr)

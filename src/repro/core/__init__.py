@@ -11,7 +11,7 @@ from repro.core.jobqueue import (
 )
 from repro.core.cluster import KubeCluster, Node, Pod, PodPhase
 from repro.core.matchmaker import (
-    HAVE_JAX, JaxMatchmaker, MatchPlan, MatchProblem, Matchmaker,
+    JaxMatchmaker, MatchPlan, MatchProblem, Matchmaker,
     NumpyMatchmaker, RESOURCE_KEYS, ScanMatchmaker, make_matchmaker,
     matchmaker_names, register_matchmaker,
 )

@@ -16,8 +16,8 @@ from repro.core.matchmaker.base import (
 )
 from repro.core.matchmaker.numpy_backend import NumpyMatchmaker
 from repro.core.matchmaker.scan_backend import ScanMatchmaker
-from repro.core.matchmaker.jax_backend import HAVE_JAX, JaxMatchmaker
-from repro.core.matchmaker.pallas_backend import HAVE_PALLAS, PallasMatchmaker
+from repro.core.matchmaker.jax_backend import JaxMatchmaker
+from repro.core.matchmaker.pallas_backend import PallasMatchmaker
 
 register_matchmaker("numpy", NumpyMatchmaker)
 register_matchmaker("scan", ScanMatchmaker)
@@ -25,9 +25,8 @@ register_matchmaker("jax", JaxMatchmaker)
 register_matchmaker("pallas", PallasMatchmaker)
 
 __all__ = [
-    "EXHAUSTIBLE_IDX", "FIT_EPS", "HAVE_JAX", "HAVE_PALLAS",
-    "RESOURCE_KEYS", "JaxMatchmaker", "MatchPlan", "MatchProblem",
-    "Matchmaker", "NumpyMatchmaker", "PallasMatchmaker", "ScanMatchmaker",
+    "EXHAUSTIBLE_IDX", "FIT_EPS", "RESOURCE_KEYS", "JaxMatchmaker",
+    "MatchPlan", "MatchProblem", "Matchmaker", "NumpyMatchmaker", "PallasMatchmaker", "ScanMatchmaker",
     "cohort_fits", "make_matchmaker", "matchmaker_names",
     "register_matchmaker",
 ]

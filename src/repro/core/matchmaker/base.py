@@ -45,6 +45,12 @@ EXHAUSTIBLE_IDX = (0, 1, 4)
 #: The eps added before floor() when converting free/want ratios into
 #: whole job slots (7.6/0.4 is 18.999...96 in binary floats and must
 #: count as 19 — the scan oracle never divides, so it would claim it).
+#: It does nothing in the device backends' float32 mode, which admits
+#: only integer quantities below 2**24: a non-integer ratio a/b of such
+#: integers is at least 1/b > 2**-24 below the next integer, so the
+#: floor needs no help; and 1e-9 is under half an ulp of every float32
+#: ratio >= 0.5, so ``ratio + FIT_EPS`` rounds back to ``ratio`` (below
+#: 0.5 it cannot reach the next integer either).
 FIT_EPS = 1e-9
 
 

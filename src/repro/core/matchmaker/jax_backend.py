@@ -25,10 +25,13 @@ Scale tricks (the ROADMAP's array-compiled matchmaking item):
   * **donated free buffer** — the (R, W) carry is donated to the jit,
     avoiding a defensive copy per cycle.
 
-dtype: ``float64`` (default) matches the NumPy reference bit-for-bit
-via `jax.experimental.enable_x64`.  ``float32`` is faster but only
-exact while resource quantities stay integer-valued below 2**24 — fine
-for whole-core/GPU pools, not for fractional-CPU requests.
+dtype: when the caller names none, ``float32`` on a TPU (which has no
+native float64) and ``float64`` elsewhere.  ``float64`` matches the
+NumPy reference bit-for-bit on any quantities (run under
+``jax.enable_x64``).  ``float32`` is exact only on integer quantities
+below 2**24 (and demand below 2**23) — whole cores, GPUs and GB —
+and `_prep` refuses any other problem rather than return different
+claims.
 """
 from __future__ import annotations
 
@@ -41,19 +44,38 @@ from repro.core.matchmaker.base import (
     FIT_EPS, RESOURCE_KEYS, CycleDelta, MatchPlan, MatchProblem,
 )
 
-try:                                    # gate: jax is an optional dep
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except ImportError:                     # pragma: no cover
-    jax = None
-    HAVE_JAX = False
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 _ZERO_WANT_BIG = 1e15     # ratio offset for zero-request resource lanes
 _W_LANES = 128            # worker-axis padding bucket
 _PREVIEW_LANES = 512      # preview lane floor (one trace per replay)
+
+# float32's exactness domain.  Quantities below 2**24 are exact
+# integers, and so is every free/want floor and free - want*take built
+# from them.  Demand is held to half that: the inclusive prefix sum over
+# a cohort's fits reaches up to twice its demand before the greedy
+# allocation saturates.
+_F32_QUANTITY_LIMIT = 2 ** 24
+_F32_DEMAND_LIMIT = 2 ** 23
+
+
+def exact_floor_f32(fits, free, want):
+    """Repair ``fits = floor(min_r free_r/want_r)`` computed in float32 on
+    a TPU, whose float32 division is not correctly rounded: an exact
+    multiple can come out just below its quotient and floor one short
+    (or a near-multiple just above and floor one long).  On the integer
+    quantities float32 admits, each quotient is off by at most one, and
+    one step each way restores the exact floor: ``fits*want`` is exact
+    below 2**24 and otherwise rounds to a value still above ``free``.
+    Shapes: fits (1, W), free (R, W), want (R, 1); zero-request rows
+    never veto.  A no-op where division is exact (the host)."""
+    need = fits * want
+    up = jnp.min(jnp.where(need + want <= free, 1.0, 0.0),
+                 axis=0, keepdims=True)
+    down = jnp.max(jnp.where(need > free, 1.0, 0.0), axis=0, keepdims=True)
+    return fits + up - down
 
 
 def _make_steps(unroll: int):
@@ -67,6 +89,8 @@ def _make_steps(unroll: int):
         d = jnp.minimum(d, left)
         ratio = freeT / safe[:, None] + big[:, None]
         fits = jnp.maximum(jnp.floor(jnp.min(ratio, axis=0) + FIT_EPS), 0.0)
+        if freeT.dtype == jnp.float32:
+            fits = exact_floor_f32(fits[None, :], freeT, want[:, None])[0]
         # capping fits at d leaves the greedy prefix allocation exact
         # (prefix sums below d are uncapped; above d both saturate) and
         # bounds the zero-request sentinel lanes; crow is uint8 (the
@@ -234,12 +258,11 @@ class JaxMatchmaker:
 
     name = "jax"
 
-    def __init__(self, *, dtype: str = "float64", chunk: int = 64,
+    def __init__(self, *, dtype: str | None = None, chunk: int = 64,
                  unroll: int = 4):
-        if not HAVE_JAX:
-            raise ImportError(
-                "matchmaker='jax' needs the jax package; install jax or "
-                "use matchmaker='numpy'")
+        if dtype is None:
+            dtype = ("float32" if jax.default_backend() == "tpu"
+                     else "float64")
         if dtype not in ("float64", "float32"):
             raise ValueError(f"dtype must be float64|float32, got {dtype!r}")
         self.dtype = dtype
@@ -289,21 +312,29 @@ class JaxMatchmaker:
         on the bucket as a fresh trace (which it was, just earlier)."""
         chunk, Wp = self.chunk, _PREVIEW_LANES
         R = len(RESOURCE_KEYS)
-        dt = jnp.float64 if self.dtype == "float64" else jnp.float32
-
-        def go():
-            z = lambda *s: jnp.zeros(s, dtype=dt)
+        with self._precision():
+            z = lambda *s: jnp.zeros(s, dtype=self.dtype)
             self._fn_preview(
                 z(1, R, Wp), z(1, 1, chunk), z(1, chunk, R),
-                jnp.ones((1, chunk, R), dtype=dt), z(1, chunk, R),
+                jnp.ones((1, chunk, R), dtype=self.dtype), z(1, chunk, R),
                 jnp.zeros((1, chunk, Wp), dtype=jnp.uint8),
             ).block_until_ready()
 
-        if self.dtype == "float64":
-            with enable_x64():
-                go()
-        else:
-            go()
+    def _precision(self):
+        """64-bit JAX types on for float64, and explicitly OFF for
+        float32, so no Python scalar of the float32 path traces as a
+        64-bit value (Mosaic refuses those)."""
+        return jax.enable_x64(self.dtype == "float64")
+
+    def _require_exact(self, what: str, x, limit: int = _F32_QUANTITY_LIMIT):
+        """In float32, refuse values outside its exact domain."""
+        if self.dtype != "float32":
+            return
+        x = np.asarray(x, dtype=np.float64)
+        if not (np.all(np.floor(x) == x) and np.all(np.abs(x) < limit)):
+            raise ValueError(
+                f"float32 matchmaking is exact only on integer {what} "
+                f"below {limit}; this problem needs dtype='float64'")
 
     def _prep(self, p: MatchProblem, active=None, *, lanes=None):
         """Order-permuted, padded host arrays (pad cohorts have demand 0
@@ -312,6 +343,9 @@ class JaxMatchmaker:
         granularity — the preview path passes a power-of-two bucket so
         a pool growing through many widths retraces once or twice per
         run instead of once per 128-lane step."""
+        self._require_exact("requests", p.requests)
+        self._require_exact("free capacity", p.free)
+        self._require_exact("demand", p.demand, _F32_DEMAND_LIMIT)
         C, W = p.compat.shape
         R = p.requests.shape[1]
         chunk = self.chunk
@@ -349,20 +383,14 @@ class JaxMatchmaker:
         req_live = np.where((d_o > 0)[:, None], req_o, np.inf)
         chunk_min = req_live.reshape(-1, chunk, R).min(axis=1)
         nch = Cp // chunk
+        if budget is not None:
+            self._require_exact("budget", budget)
         left = math.inf if budget is None else float(budget)
         self._note_call("match", (nch, Wp, self.dtype))
 
-        if self.dtype == "float64":
-            with enable_x64():
-                takes_j, freeT_j, ran_j = self._run(
-                    jnp.float64, freeT, left, req_o, safe, big, d_o,
-                    crow_o, chunk_min, nch, chunk, R, Wp)
-                takes_j = np.asarray(takes_j)
-                freeT_j = np.asarray(freeT_j)
-                ran = np.asarray(ran_j)
-        else:
+        with self._precision():
             takes_j, freeT_j, ran_j = self._run(
-                jnp.float32, freeT, left, req_o, safe, big, d_o,
+                self.dtype, freeT, left, req_o, safe, big, d_o,
                 crow_o, chunk_min, nch, chunk, R, Wp)
             takes_j = np.asarray(takes_j)
             freeT_j = np.asarray(freeT_j, dtype=np.float64)
@@ -394,8 +422,12 @@ class JaxMatchmaker:
         C, W = p.compat.shape
         R = p.requests.shape[1]
         chunk = self.chunk
-        dt = jnp.float64 if self.dtype == "float64" else jnp.float32
+        dt = self.dtype
         order_key = np.asarray(p.order, dtype=np.int64).tobytes()
+        for f in frees:
+            self._require_exact("free capacity", f)
+        for dv in (demands if demands is not None else [p.demand]):
+            self._require_exact("demand", dv, _F32_DEMAND_LIMIT)
 
         def run():
             sess = self._preview_session
@@ -451,10 +483,7 @@ class JaxMatchmaker:
                 *consts)
             return order, Cp, np.asarray(absorbed)
 
-        if self.dtype == "float64":
-            with enable_x64():
-                order, Cp, absorbed = run()
-        else:
+        with self._precision():
             order, Cp, absorbed = run()
 
         flat = absorbed.reshape(N, Cp)
@@ -491,19 +520,19 @@ class JaxMatchmaker:
                 order[:C]]
             if d.free_add is not None:
                 free_addT[k, :, :W] = np.asarray(d.free_add).T
+            if d.budget is not None:
+                self._require_exact("budget", d.budget)
             budgets[k] = math.inf if d.budget is None else float(d.budget)
+        # claims only shrink demand and free capacity, so the deltas'
+        # running totals bound every value the K cycles carry
+        self._require_exact("free capacity",
+                            freeT + np.cumsum(free_addT, axis=0))
+        self._require_exact("demand", d_o + np.cumsum(arrivals, axis=0),
+                            _F32_DEMAND_LIMIT)
 
-        if self.dtype == "float64":
-            with enable_x64():
-                takes_j, ran_j, free_per = self._run_cycles(
-                    jnp.float64, freeT, d_o, arrivals, free_addT,
-                    budgets, req_o, safe, big, crow_o, nch, chunk, R, Wp)
-                takes_j = np.asarray(takes_j)
-                ran = np.asarray(ran_j)
-                free_per = np.asarray(free_per)
-        else:
+        with self._precision():
             takes_j, ran_j, free_per = self._run_cycles(
-                jnp.float32, freeT, d_o, arrivals, free_addT,
+                self.dtype, freeT, d_o, arrivals, free_addT,
                 budgets, req_o, safe, big, crow_o, nch, chunk, R, Wp)
             takes_j = np.asarray(takes_j)
             ran = np.asarray(ran_j)

@@ -4,28 +4,24 @@
 backend (same `_prep` padding/ordering, same scatter-back), but the
 chunked claim loop runs as ONE Pallas program with the free matrix
 resident in VMEM across every chunk (src/repro/kernels/waterfill/).
-Off-TPU the kernel runs in interpret mode, so plans stay bit-identical
-to the jax and numpy backends in float64 and CI can pin the parity
-without hardware.
+The kernel is compiled for the TPU; ``interpret=True`` evaluates it on
+the host instead, which is how the CPU tests pin bit-identity with the
+jax and numpy backends (in float64 as well as float32).
 
-Multi-cycle fusion (`match_cycles`) is inherited from the jax backend:
-the K-cycle batch is an outer lax.scan around the identical chunk
-arithmetic, so a pallas-selected pool still gets device-resident fused
-batches — the kernel covers the steady-state per-cycle path, which
-dominates the paper's demand >> supply negotiation profile.
+Multi-cycle fusion (`match_cycles`) and batched previews are inherited
+from the jax backend: the K-cycle batch is an outer lax.scan around the
+identical chunk arithmetic, so a pallas-selected pool still gets
+device-resident fused batches — the kernel covers the steady-state
+per-cycle path, which dominates the paper's demand >> supply
+negotiation profile.
 """
 from __future__ import annotations
 
-import numpy as np
-
-from repro.core.matchmaker.jax_backend import HAVE_JAX, JaxMatchmaker
-
-try:                                    # gate: pallas rides on jax
-    from repro.kernels.waterfill import waterfill
-    HAVE_PALLAS = HAVE_JAX
-except ImportError:                     # pragma: no cover
-    waterfill = None
-    HAVE_PALLAS = False
+from repro.core.matchmaker.jax_backend import JaxMatchmaker
+# a module reference, not the function: the kernel imports FIT_EPS from
+# repro.core, so when the kernel package is imported first this module
+# runs while `ops` is still initialising
+from repro.kernels.waterfill import ops as waterfill_ops
 
 
 class PallasMatchmaker(JaxMatchmaker):
@@ -33,24 +29,16 @@ class PallasMatchmaker(JaxMatchmaker):
 
     name = "pallas"
 
-    def __init__(self, *, dtype: str = "float64", chunk: int = 64,
-                 unroll: int = 4, interpret: bool | None = None):
-        if not HAVE_PALLAS:
-            raise ImportError(
-                "matchmaker='pallas' needs jax with pallas support; "
-                "use matchmaker='jax' or 'numpy'")
+    def __init__(self, *, dtype: str | None = None, chunk: int = 64,
+                 unroll: int = 4, interpret: bool = False):
         super().__init__(dtype=dtype, chunk=chunk, unroll=unroll)
         self.interpret = interpret
 
     def _run(self, dt, freeT, left, req_o, safe, big, d_o, crow_o,
              chunk_min, nch, chunk, R, Wp):
-        return waterfill(
-            freeT, float(left),
-            np.ascontiguousarray(req_o.reshape(nch, chunk, R)),
-            np.ascontiguousarray(safe.reshape(nch, chunk, R)),
-            np.ascontiguousarray(big.reshape(nch, chunk, R)),
-            d_o.reshape(nch, chunk),
-            np.ascontiguousarray(crow_o.reshape(nch, chunk, Wp)),
-            chunk_min,
-            dtype=dt, interpret=self.interpret,
+        # safe/big are re-derived from the requests inside the kernel
+        return waterfill_ops.waterfill(
+            freeT, left, req_o.reshape(nch, chunk, R),
+            d_o.reshape(nch, chunk), crow_o.reshape(nch, chunk, Wp),
+            chunk_min, dtype=dt, interpret=self.interpret,
         )

@@ -1,68 +1,47 @@
-"""Water-fill entry point: shape adaptation + backend dispatch.
+"""Water-fill entry point: shape adaptation for the Pallas kernel.
 
 `waterfill` takes the matchmaker's chunked device layout — the same
-(nch, chunk, R) / (R, Wp) tensors the jax backend's scan consumes — pads
-the tiny resource axis to the TPU's 8-sublane tile, and runs the Pallas
-kernel.  Off-TPU (CI, CPU dry-runs) the kernel executes in interpret
-mode: the identical program graph evaluated by XLA:CPU, which is what
-lets the differential suite pin bit-identity against the jax and numpy
-backends in float64 without TPU hardware.
-
-Resource-axis padding is semantics-free by the same convention the
-matchmaker uses for zero-request lanes: padded `want` rows are 0, `safe`
-1, `big` the sentinel (their fit ratio is huge and never the min), the
-padded free rows are 0 and never decremented, and padded `chunk_min`
-lanes are 0 so the drain guard's `free >= 0` test cannot veto a chunk.
+(nch, chunk, R) / (R, Wp) tensors the jax backend's scan consumes —
+lays the per-cohort scalars out as one row per chunk for the
+kernel's SMEM blocks, and runs
+the kernel.  It compiles for the TPU unless the caller passes
+``interpret=True``: the identical program evaluated by XLA on the host,
+which is how the CPU differential suite pins bit-identity against the
+jax and numpy backends.  Nothing picks interpret mode by itself, so a
+TPU caller can never run the interpreter by accident.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.waterfill.kernel import _R_SUBLANES, waterfill_pallas
+from repro.kernels.waterfill.kernel import waterfill_pallas
 from repro.kernels.waterfill.ref import waterfill_reference
-
-
-def _pad_r(x: np.ndarray, axis: int, value: float) -> np.ndarray:
-    pad = (-x.shape[axis]) % _R_SUBLANES
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return np.pad(x, widths, constant_values=value)
 
 
 def waterfill(
     freeT: np.ndarray,       # (R, Wp)
     left: float,             # claim budget (may be inf)
     want: np.ndarray,        # (nch, chunk, R)
-    safe: np.ndarray,        # (nch, chunk, R)
-    big: np.ndarray,         # (nch, chunk, R)
     demand: np.ndarray,      # (nch, chunk)
     crow: np.ndarray,        # (nch, chunk, Wp) uint8
     chunk_min: np.ndarray,   # (nch, R)
     *,
     dtype,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Returns (takes (nch, chunk, Wp) int32, freeT_after (R, Wp),
     ran (nch,) bool) — the jax backend's `_run` contract."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    R = freeT.shape[0]
-    takes, ran, free_out, _left_out = waterfill_pallas(
-        jnp.asarray(_pad_r(freeT, 0, 0.0), dtype=dtype),
-        jnp.full((1, 1), left, dtype=dtype),
-        jnp.asarray(_pad_r(want, 2, 0.0), dtype=dtype),
-        jnp.asarray(_pad_r(safe, 2, 1.0), dtype=dtype),
-        jnp.asarray(_pad_r(big, 2, 1e15), dtype=dtype),
-        jnp.asarray(demand, dtype=dtype),
+    nch = crow.shape[0]
+    row = lambda x: jnp.asarray(x.reshape(nch, 1, -1), dtype=dtype)
+    takes, ran, free_out = waterfill_pallas(
+        jnp.asarray(freeT, dtype=dtype),
+        jnp.full((1,), left, dtype=dtype),
+        row(want), row(demand), row(chunk_min),
         jnp.asarray(crow),                           # uint8 mask
-        jnp.asarray(_pad_r(chunk_min, 1, 0.0), dtype=dtype),
         interpret=interpret,
     )
-    return takes, free_out[:R], (ran[:, 0] != 0)
+    return takes, free_out, ran.reshape(nch) != 0
 
 
 __all__ = ["waterfill", "waterfill_reference"]

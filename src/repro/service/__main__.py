@@ -37,6 +37,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.service.http import serve, serve_in_thread
 from repro.service.pool import PoolClient, PoolService, RemoteClient
 from repro.workload.compare import FEDERATION_INI
@@ -389,4 +390,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -35,6 +35,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.workload.compare import (
     compare, comparison_table, run_policy, standard_policies,
     standard_policy,
@@ -248,4 +249,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -23,15 +23,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 from repro.core.classad import ClassAdExpr
 from repro.core.config import load_ini, dump_ini
 from repro.core.jobqueue import Job, JobQueue
-from repro.core.matchmaker import HAVE_JAX, make_matchmaker
+from repro.core.matchmaker import make_matchmaker
 from repro.core.matchmaker.base import (
     CycleDelta, match_cycles, sequential_match_cycles,
 )
 from repro.core.worker import Collector, Worker
 
 from test_matchmaker_differential import random_problem
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 
 
 # -- backend: fused K-cycle dispatch vs K-loop reference ---------------------
@@ -53,7 +51,6 @@ def random_deltas(rng, p, K):
     return deltas
 
 
-@needs_jax
 @pytest.mark.parametrize("K", [1, 2, 8])
 def test_match_cycles_bit_identical_to_sequential(K):
     jaxmm = make_matchmaker("jax")
@@ -102,7 +99,6 @@ def full_claim_map(q):
                   for j in q.jobs() if j.claimed_by is not None)
 
 
-@needs_jax
 @pytest.mark.parametrize("K", [1, 2, 8])
 def test_staged_flush_identical_to_sequential(K):
     """Random interleaved waves: whatever mix of fused batches and
@@ -130,7 +126,6 @@ def test_staged_flush_identical_to_sequential(K):
             f"K={K} trial={trial}"
 
 
-@needs_jax
 def test_staged_batch_takes_fused_path_on_disjoint_waves():
     """Waves of fresh cohort shapes never re-seed a drained cohort, so
     the batch must go through the fused jit (not the fallback) and
@@ -152,7 +147,6 @@ def test_staged_batch_takes_fused_path_on_disjoint_waves():
     assert full_claim_map(q_s) == full_claim_map(q_r)
 
 
-@needs_jax
 def test_mid_batch_quiesce_flushes_and_matches():
     """An external op mid-batch (snapshot, reconfig, ...) quiesces a
     half-full staging buffer; the partial flush plus the follow-on
@@ -176,7 +170,6 @@ def test_mid_batch_quiesce_flushes_and_matches():
     assert full_claim_map(q_s) == full_claim_map(q_r)
 
 
-@needs_jax
 def test_worker_churn_mid_batch_forces_fallback():
     """A worker booting between staged cycles changes the pool
     fingerprint — the batch must replay sequentially (the fused problem
@@ -207,7 +200,6 @@ def test_worker_churn_mid_batch_forces_fallback():
     assert full_claim_map(q_s) == full_claim_map(q_r)
 
 
-@needs_jax
 def test_reseed_hazard_forces_fallback():
     """A cohort that fully drains mid-batch and then receives new
     arrivals would re-seed its FIFO sort key in the sequential path —
@@ -246,7 +238,6 @@ def test_noop_memo_skips_unchanged_cycles():
 
 # -- simulation: negotiation_batch=K engines match batch=1 -------------------
 
-@needs_jax
 def test_simulation_batch_knob_preserves_claim_map():
     from repro.core import (
         ProvisionerConfig, Simulation, gpu_job, onprem_nodes,

@@ -30,11 +30,8 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.core import ProvisionerConfig, Simulation, gpu_job, onprem_nodes
-from repro.core.matchmaker import HAVE_JAX
 from repro.workload.generators import diurnal_day
 from repro.workload.replay import replay_trace
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 
 
 def fusion_sim(batch, *, matchmaker="numpy", nodes=2):
@@ -113,8 +110,7 @@ def _replay(batch, matchmaker):
     return sim
 
 
-@pytest.mark.parametrize("matchmaker", [
-    "numpy", pytest.param("jax", marks=needs_jax)])
+@pytest.mark.parametrize("matchmaker", ["numpy", "jax"])
 def test_diurnal_replay_bit_identical_across_batch(matchmaker):
     ref = _replay(1, matchmaker)
     ref_sig = completion_signature(ref)

@@ -27,16 +27,23 @@ from repro.core.classad import ClassAdExpr
 from repro.core.fairshare import Accountant, ScheddSpec
 from repro.core.jobqueue import Job, JobQueue
 from repro.core.matchmaker import (
-    HAVE_JAX, HAVE_PALLAS, MatchPlan, MatchProblem, NumpyMatchmaker,
-    ScanMatchmaker, make_matchmaker,
+    MatchPlan, MatchProblem, NumpyMatchmaker, ScanMatchmaker,
+    make_matchmaker,
 )
 from repro.core.worker import Collector, Worker
 
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
-needs_pallas = pytest.mark.skipif(not HAVE_PALLAS,
-                                  reason="jax/pallas not installed")
-
 R = 6   # RESOURCE_KEYS width; column 0 is cpus
+
+# the device backends' two modes: float64 is exact on any quantities,
+# float32 (the TPU's) only on integer ones — fractional problems run in
+# float64 alone
+DTYPES = ("float32", "float64")
+DTYPE_CASES = [(False, "float32"), (False, "float64"), (True, "float64")]
+
+
+def pallas(dtype="float64"):
+    """The Pallas backend evaluated on the host (interpret mode)."""
+    return make_matchmaker("pallas", dtype=dtype, interpret=True)
 
 
 def random_problem(rng, *, C=None, W=None, fractional=False,
@@ -77,10 +84,9 @@ def assert_plans_equal(a: MatchPlan, b: MatchPlan, label: str):
 
 # -- pure problems: numpy vs jax ---------------------------------------------
 
-@needs_jax
-@pytest.mark.parametrize("fractional", [False, True])
-def test_jax_identical_on_random_problems(fractional):
-    jaxmm = make_matchmaker("jax")
+@pytest.mark.parametrize("fractional,dtype", DTYPE_CASES)
+def test_jax_identical_on_random_problems(fractional, dtype):
+    jaxmm = make_matchmaker("jax", dtype=dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(7 + fractional)
     for trial in range(40):
@@ -89,9 +95,9 @@ def test_jax_identical_on_random_problems(fractional):
                            f"trial={trial} fractional={fractional}")
 
 
-@needs_jax
-def test_jax_identical_under_budget_and_active():
-    jaxmm = make_matchmaker("jax")
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_jax_identical_under_budget_and_active(dtype):
+    jaxmm = make_matchmaker("jax", dtype=dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(11)
     for trial in range(25):
@@ -109,11 +115,11 @@ def test_jax_identical_under_budget_and_active():
                            f"both trial={trial}")
 
 
-@needs_jax
-def test_jax_padding_boundaries():
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_jax_padding_boundaries(dtype):
     """Cohort/worker counts straddling the chunk (256) and lane (128)
     buckets — padding rows must take nothing."""
-    jaxmm = make_matchmaker("jax")
+    jaxmm = make_matchmaker("jax", dtype=dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(13)
     for C in (1, 255, 256, 257):
@@ -123,12 +129,12 @@ def test_jax_padding_boundaries():
                                f"C={C} W={W}")
 
 
-@needs_jax
-def test_jax_drain_guard_exact_when_pool_exhausts():
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_jax_drain_guard_exact_when_pool_exhausts(dtype):
     """Demand >> supply: later chunks are skipped by the drain guard —
     skipping must be claim-exact, including zero-CPU-request cohorts
     (they disarm the guard)."""
-    jaxmm = make_matchmaker("jax")
+    jaxmm = make_matchmaker("jax", dtype=dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(17)
     p = random_problem(rng, C=600, W=4)
@@ -140,14 +146,13 @@ def test_jax_drain_guard_exact_when_pool_exhausts():
 
 # -- pure problems: pallas water-fill kernel (interpret mode) ----------------
 
-@needs_pallas
-@pytest.mark.parametrize("fractional", [False, True])
-def test_pallas_interpret_identical_on_random_problems(fractional):
+@pytest.mark.parametrize("fractional,dtype", DTYPE_CASES)
+def test_pallas_interpret_identical_on_random_problems(fractional, dtype):
     """The Pallas kernel in interpret mode (what CPU CI runs) must be
     bit-identical to BOTH the jax scan and the numpy reference — the
-    same float64 arithmetic in a different program shape."""
-    pmm = make_matchmaker("pallas")
-    jaxmm = make_matchmaker("jax")
+    same arithmetic in a different program shape."""
+    pmm = pallas(dtype)
+    jaxmm = make_matchmaker("jax", dtype=dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(31 + fractional)
     for trial in range(12):
@@ -163,12 +168,12 @@ def test_pallas_interpret_identical_on_random_problems(fractional):
                                       err_msg=label + " free (bitwise)")
 
 
-@needs_pallas
-def test_pallas_interpret_budget_and_drain():
-    """Claim budgets thread through the kernel's VMEM scalar, and the
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_pallas_interpret_budget_and_drain(dtype):
+    """Claim budgets thread through the kernel's SMEM scalar, and the
     in-kernel drain guard must skip chunks claim-exactly when the pool
     exhausts (demand >> supply)."""
-    pmm = make_matchmaker("pallas")
+    pmm = pallas(dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(37)
     for trial in range(8):
@@ -181,11 +186,11 @@ def test_pallas_interpret_budget_and_drain():
     assert_plans_equal(ref.match(p), pmm.match(p), "drain")
 
 
-@needs_pallas
-def test_pallas_padding_boundaries():
-    """Chunk/lane bucket edges plus the kernel's own resource-axis pad
-    (6 -> 8 sublanes) — padding lanes must never constrain a fit."""
-    pmm = make_matchmaker("pallas")
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_pallas_padding_boundaries(dtype):
+    """Chunk/lane bucket edges — padding cohorts and workers must take
+    nothing."""
+    pmm = pallas(dtype)
     ref = NumpyMatchmaker()
     rng = np.random.default_rng(41)
     for C in (1, 63, 64, 65):
@@ -194,13 +199,59 @@ def test_pallas_padding_boundaries():
             assert_plans_equal(ref.match(p), pmm.match(p), f"C={C} W={W}")
 
 
-@needs_pallas
 def test_collector_run_cycle_pallas_equals_numpy():
     for seed in range(3):
         ca, qa = build_pool("numpy", rng_seed=seed)
-        cb, qb = build_pool("pallas", rng_seed=seed)
+        cb, qb = build_pool(pallas(), rng_seed=seed)
         assert ca.run_cycle(qa, 0.0) == cb.run_cycle(qb, 0.0)
         assert claim_map(qa) == claim_map(qb), f"seed={seed}"
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_float32_refuses_problems_outside_its_exact_domain(backend):
+    """float32 is exact only on integer quantities below 2**24 (demand
+    below 2**23): anything else must raise, never return claims that
+    differ from the reference."""
+    mm = (pallas("float32") if backend == "pallas"
+          else make_matchmaker("jax", dtype="float32"))
+    rng = np.random.default_rng(43)
+    frac = random_problem(rng, fractional=True)
+    frac.requests[0, 0] = 1.5
+    with pytest.raises(ValueError, match="float64"):
+        mm.match(frac)
+    big_free = random_problem(rng)
+    big_free.free[0, 2] = 2.0 ** 24
+    with pytest.raises(ValueError, match="float64"):
+        mm.match(big_free)
+    big_demand = random_problem(rng)
+    big_demand.demand[0] = 2 ** 23
+    with pytest.raises(ValueError, match="float64"):
+        mm.match(big_demand)
+    ok = random_problem(rng)
+    assert_plans_equal(NumpyMatchmaker().match(ok), mm.match(ok), "in range")
+
+
+def test_exact_floor_f32_repairs_an_off_by_one_division():
+    """A TPU's float32 division is not correctly rounded: an exact
+    multiple can floor one short, a near-multiple one long.  From
+    either, the repair must land on the exact floor of the integers."""
+    import jax.numpy as jnp
+    from repro.core.matchmaker.jax_backend import exact_floor_f32
+    rng = np.random.default_rng(47)
+    Wn = 512
+    want = rng.integers(0, 4_096, size=(R, 1)).astype(np.float64)
+    want[0] = np.maximum(want[0], 1)                # cpus always asked
+    k = rng.integers(0, 4_096, size=(R, Wn))
+    rem = (rng.random((R, Wn)) < 0.5) * rng.integers(0, 4_096, size=(R, Wn))
+    free = want * k + np.minimum(rem, np.maximum(want - 1, 0))
+    exact = np.min(np.where(want > 0, free // np.maximum(want, 1), np.inf),
+                   axis=0, keepdims=True)
+    for off in (-1.0, 0.0, 1.0):
+        got = exact_floor_f32(jnp.asarray(exact + off, jnp.float32),
+                              jnp.asarray(free, jnp.float32),
+                              jnp.asarray(want, jnp.float32))
+        np.testing.assert_array_equal(np.asarray(got), exact,
+                                      err_msg=f"off={off}")
 
 
 # -- pure problems: numpy vs scan oracle -------------------------------------
@@ -227,7 +278,6 @@ def test_scan_oracle_matches_reference_cohort_contiguous():
 
 # -- hypothesis variants (skip cleanly when not installed) -------------------
 
-@needs_jax
 @settings(max_examples=30, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=2**31 - 1),
        fractional=st.booleans())
@@ -282,7 +332,6 @@ def claim_map(q):
     return {j.jid: j.claimed_by for j in q.jobs() if j.claimed_by}
 
 
-@needs_jax
 def test_collector_run_cycle_jax_equals_numpy():
     for seed in range(5):
         ca, qa = build_pool("numpy", rng_seed=seed)
@@ -334,7 +383,6 @@ def build_federation(matchmaker, rng_seed=1):
     return col, queues, acct
 
 
-@needs_jax
 def test_flocking_fairshare_jax_equals_numpy():
     ca, qsa, aa = build_federation("numpy")
     cb, qsb, ab = build_federation("jax")
@@ -348,7 +396,6 @@ def test_flocking_fairshare_jax_equals_numpy():
     assert sa == sb
 
 
-@needs_jax
 def test_flocking_fairshare_split_respects_quotas_both_backends():
     """The 3:1:2-quota pool split must come out identical (and quota-
     proportional) on both backends."""

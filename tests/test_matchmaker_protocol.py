@@ -15,7 +15,7 @@ from repro.core.classad import ClassAdExpr
 from repro.core.config import ProvisionerConfig, dump_ini, load_ini
 from repro.core.jobqueue import Job, JobQueue
 from repro.core.matchmaker import (
-    HAVE_JAX, MatchPlan, MatchProblem, Matchmaker, NumpyMatchmaker,
+    MatchPlan, MatchProblem, Matchmaker, NumpyMatchmaker,
     ScanMatchmaker, make_matchmaker, matchmaker_names,
 )
 from repro.core.simulation import Simulation
@@ -81,7 +81,6 @@ def test_backends_satisfy_protocol():
     assert isinstance(ScanMatchmaker(), Matchmaker)
 
 
-@pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 def test_jax_backend_config_validation():
     from repro.core.matchmaker import JaxMatchmaker
     assert isinstance(JaxMatchmaker(), Matchmaker)

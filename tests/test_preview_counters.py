@@ -21,10 +21,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.core.classad import ClassAdExpr
 from repro.core.jobqueue import Job, JobQueue
-from repro.core.matchmaker import HAVE_JAX
 from repro.core.worker import Collector, Worker
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 
 
 def add_worker(col, name, ad, start="true", booted=0.0):
@@ -41,7 +38,6 @@ def n_claimed(q):
 
 # -- satellite 1: path-labelled jit-compile counter ---------------------------
 
-@needs_jax
 def test_jit_compiles_labelled_by_entry_path():
     col = Collector(matchmaker="jax", telemetry=True)
     prof = col.profiler

@@ -26,12 +26,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 from test_matchmaker_differential import random_problem
 
-from repro.core.matchmaker import (
-    HAVE_JAX, NumpyMatchmaker, make_matchmaker,
-)
+from repro.core.matchmaker import NumpyMatchmaker, make_matchmaker
 from repro.core.matchmaker.base import preview_many, sequential_preview_many
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 
 
 def random_frees(rng, p, n):
@@ -51,7 +47,6 @@ def assert_batches_equal(got, want, label):
             np.asarray(g), np.asarray(w), err_msg=f"{label} cand={i}")
 
 
-@needs_jax
 @pytest.mark.parametrize("fractional", [False, True])
 def test_preview_many_matches_sequential_numpy(fractional):
     jaxmm = make_matchmaker("jax")
@@ -67,7 +62,6 @@ def test_preview_many_matches_sequential_numpy(fractional):
                 got, want, f"trial={trial} n={n} fractional={fractional}")
 
 
-@needs_jax
 def test_preview_many_per_candidate_demands():
     jaxmm = make_matchmaker("jax")
     ref = NumpyMatchmaker()
@@ -83,7 +77,6 @@ def test_preview_many_per_candidate_demands():
         assert_batches_equal(got, want, f"trial={trial} n={n}")
 
 
-@needs_jax
 def test_preview_many_session_reuse_and_order_invalidation():
     """A stable session token keeps cohort constants on device across
     calls; results must stay identical to fresh dispatches, and a
@@ -112,7 +105,6 @@ def test_preview_many_session_reuse_and_order_invalidation():
     assert_batches_equal(got, want, "order change under stable token")
 
 
-@needs_jax
 def test_preview_many_padding_boundaries():
     jaxmm = make_matchmaker("jax")
     ref = NumpyMatchmaker()
@@ -126,7 +118,6 @@ def test_preview_many_padding_boundaries():
             assert_batches_equal(got, want, f"C={C} W={W}")
 
 
-@needs_jax
 def test_preview_many_marks_preview_call():
     """The backend self-reports the dedicated preview entry path (the
     profiler's path-labelled jit counter reads this)."""
@@ -138,7 +129,6 @@ def test_preview_many_marks_preview_call():
     assert "compiled" in jaxmm.last_call
 
 
-@needs_jax
 def test_dispatcher_routes_jax_and_falls_back_sequential():
     rng = np.random.default_rng(139)
     p = random_problem(rng)
