@@ -1,0 +1,3 @@
+"""`preview_ms`, reported in a cell whose window holds too few passes for
+an end-to-end tail; it moves `claims_per_s` there."""
+from bench.metrics.preview_ms import read  # noqa: F401

@@ -1,0 +1,3 @@
+"""`window_compiles`, reported in a cell whose window holds too few passes for
+an end-to-end tail; it moves `claims_per_s` there."""
+from bench.metrics.window_compiles import read  # noqa: F401
