@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import heapq
 import itertools
+import time
 from typing import Callable
 
 EventFn = Callable[[float], None]
@@ -90,11 +91,15 @@ class PeriodicHandle:
 
 
 class EventLoop:
-    """heapq-based scheduler; the simulation drives it one event at a time."""
+    """heapq-based scheduler; the simulation drives it one event at a time.
 
-    def __init__(self, t0: float = 0.0):
+    With a `profiler` (the telemetry's `CycleProfiler`), each fired
+    event runs inside a `repro.event` span labelled by its kind."""
+
+    def __init__(self, t0: float = 0.0, *, profiler=None):
         self.now = t0
         self.fired = 0
+        self.profiler = profiler
         self._heap: list[tuple[float, int, int, EventHandle, EventFn]] = []
         self._seq = itertools.count()
 
@@ -154,14 +159,24 @@ class EventLoop:
 
     def fire_next(self) -> float | None:
         """Fire exactly one event at its exact timestamp; returns the
-        timestamp, or None when the heap is empty."""
+        timestamp, or None when the heap is empty.  The event's span
+        covers popping it too."""
+        prof = self.profiler
+        t0 = time.perf_counter() if prof is not None else 0.0
         self._skim()
         if not self._heap:
             return None
-        at, _prio, _seq, _handle, fn = heapq.heappop(self._heap)
+        at, _prio, _seq, handle, fn = heapq.heappop(self._heap)
         self.now = max(self.now, at)
         self.fired += 1
-        fn(at)
+        if prof is None:
+            fn(at)
+        else:
+            prof.enter_event(handle.name, t0)
+            try:
+                fn(at)
+            finally:
+                prof.exit()
         return at
 
     def run_until(self, t_end: float,

@@ -25,6 +25,13 @@ Scale tricks (the ROADMAP's array-compiled matchmaking item):
   * **donated free buffer** — the (R, W) carry is donated to the jit,
     avoiding a defensive copy per cycle.
 
+The three device programs compile as ``jit_waterfill_match``,
+``jit_waterfill_cycles`` and ``jit_waterfill_preview``: stable names in
+a profiler trace.  Each call's ``last_call`` says its padding bucket,
+whether the bucket was fresh, its round trip (``repro.device.roundtrip``:
+first host-to-device copy to the answer on the host) and the bytes of
+the padded arrays sent and fetched.
+
 dtype: when the caller names none, ``float32`` on a TPU (which has no
 native float64) and ``float64`` elsewhere.  ``float64`` matches the
 NumPy reference bit-for-bit on any quantities (run under
@@ -36,6 +43,7 @@ claims.
 from __future__ import annotations
 
 import math
+import time
 from functools import lru_cache, partial
 
 import numpy as np
@@ -43,6 +51,7 @@ import numpy as np
 from repro.core.matchmaker.base import (
     FIT_EPS, RESOURCE_KEYS, CycleDelta, MatchPlan, MatchProblem,
 )
+from repro.observability import trace_me
 
 import jax
 import jax.numpy as jnp
@@ -147,7 +156,8 @@ def _build_scan(chunk: int, unroll: int):
     (config, bucket) pair once."""
     _inner, chunk_step = _make_steps(unroll)
 
-    def fn(freeT, left, want_s, safe_s, big_s, d_s, crow_s, chunk_min):
+    def waterfill_match(freeT, left, want_s, safe_s, big_s, d_s, crow_s,
+                        chunk_min):
         (freeT, left), (takes, ran) = lax.scan(
             chunk_step, (freeT, left),
             (want_s, safe_s, big_s, d_s, crow_s, chunk_min))
@@ -156,7 +166,7 @@ def _build_scan(chunk: int, unroll: int):
         # converting a matrix of zeros
         return takes, freeT, ran
 
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(waterfill_match, donate_argnums=(0,))
 
 
 @lru_cache(maxsize=None)
@@ -199,7 +209,12 @@ def _build_preview_scan(chunk: int, unroll: int):
             (want_s, safe_s, big_s, d_s, crow_s))
         return absorbed                       # (nch, chunk) int32
 
-    return jax.jit(jax.vmap(one, in_axes=(0, 0, None, None, None, None)))
+    many = jax.vmap(one, in_axes=(0, 0, None, None, None, None))
+
+    def waterfill_preview(freeT, d_s, want_s, safe_s, big_s, crow_s):
+        return many(freeT, d_s, want_s, safe_s, big_s, crow_s)
+
+    return jax.jit(waterfill_preview)
 
 
 @lru_cache(maxsize=None)
@@ -234,8 +249,8 @@ def _build_cycles_scan(chunk: int, unroll: int):
         d_s = d_s - jnp.sum(takes, axis=2).astype(d_s.dtype)
         return (freeT, d_s), (takes, ran, freeT)
 
-    def fn(freeT, d_s, arrivals, free_addT, budgets,
-           want_s, safe_s, big_s, crow_s):
+    def waterfill_cycles(freeT, d_s, arrivals, free_addT, budgets,
+                         want_s, safe_s, big_s, crow_s):
         # deltas scan over cycles; the per-chunk tensors are loop
         # constants (closed over via broadcast in xs would copy K-fold)
         def step(carry, x):
@@ -250,7 +265,45 @@ def _build_cycles_scan(chunk: int, unroll: int):
 
     # no buffer donation here: the per-cycle freeT snapshots are emitted
     # as scan ys, so the input buffers stay live for the whole dispatch
-    return jax.jit(fn)
+    return jax.jit(waterfill_cycles)
+
+
+class _RoundTrip:
+    """One matchmaker call's device round trip, from its first
+    host-to-device copy (`put`) to its answer on the host (`get`):
+    ``roundtrip_s``, ``h2d_bytes`` and ``d2h_bytes`` (the nbytes of the
+    padded device arrays sent and fetched) added to ``last_call``, and,
+    with `spans` on and a profiler session collecting, a
+    `repro.device.roundtrip` TraceMe."""
+
+    __slots__ = ("lc", "spans", "h2d", "d2h", "t0", "tm")
+
+    def __init__(self, lc: dict, spans: bool):
+        self.lc = lc
+        self.spans = spans
+        self.h2d = self.d2h = 0
+
+    def __enter__(self):
+        self.tm = (trace_me("repro.device.roundtrip", path=self.lc["kind"],
+                            fresh=self.lc["compiled"])
+                   if self.spans else None)
+        self.t0 = time.perf_counter()
+        return self
+
+    def put(self, x, dtype=None):
+        a = jnp.asarray(x, dtype=dtype)
+        self.h2d += a.nbytes
+        return a
+
+    def get(self, a, dtype=None) -> np.ndarray:
+        self.d2h += a.nbytes
+        return np.asarray(a, dtype=dtype)
+
+    def __exit__(self, *exc):
+        self.lc.update(roundtrip_s=time.perf_counter() - self.t0,
+                       h2d_bytes=self.h2d, d2h_bytes=self.d2h)
+        if self.tm is not None:
+            self.tm.__exit__(*exc)
 
 
 class JaxMatchmaker:
@@ -292,12 +345,17 @@ class JaxMatchmaker:
         # profiler reads `last_call` after each match.
         self._seen_buckets: set[tuple] = set()
         self.last_call: dict | None = None
+        #: open `repro.device.roundtrip` TraceMes; a `Collector` with
+        #: telemetry on turns this on
+        self.spans = False
 
-    def _note_call(self, kind: str, bucket: tuple):
+    def _note_call(self, kind: str, bucket: tuple) -> _RoundTrip:
+        """Start `last_call` for one call; returns its round trip."""
         compiled = bucket not in self._seen_buckets
         self._seen_buckets.add(bucket)
         self.last_call = {"kind": kind, "bucket": bucket,
                           "compiled": compiled}
+        return _RoundTrip(self.last_call, self.spans)
 
     def warm_preview(self):
         """Pre-compile the canonical preview bucket: nch=1 cohort
@@ -386,15 +444,15 @@ class JaxMatchmaker:
         if budget is not None:
             self._require_exact("budget", budget)
         left = math.inf if budget is None else float(budget)
-        self._note_call("match", (nch, Wp, self.dtype))
+        rt = self._note_call("match", (nch, Wp, self.dtype))
 
-        with self._precision():
+        with self._precision(), rt:
             takes_j, freeT_j, ran_j = self._run(
-                self.dtype, freeT, left, req_o, safe, big, d_o,
+                rt.put, self.dtype, freeT, left, req_o, safe, big, d_o,
                 crow_o, chunk_min, nch, chunk, R, Wp)
-            takes_j = np.asarray(takes_j)
-            freeT_j = np.asarray(freeT_j, dtype=np.float64)
-            ran = np.asarray(ran_j)
+            takes_j = rt.get(takes_j)
+            freeT_j = rt.get(freeT_j, np.float64)
+            ran = rt.get(ran_j)
 
         # scatter back to original cohort rows — only chunks that ran
         # (skipped chunks are all-zero by construction)
@@ -431,6 +489,7 @@ class JaxMatchmaker:
 
         def run():
             sess = self._preview_session
+            ship = None       # cohort constants to send, on a miss
             if (session is not None and sess is not None
                     and sess["token"] == session
                     and sess["shape"] == (C, W, R)
@@ -450,17 +509,10 @@ class JaxMatchmaker:
                 (order, req_o, _d_o, crow_o, _freeT, safe, big,
                  Cp, Wp) = self._prep(p, lanes=lanes)
                 nch = Cp // chunk
-                consts = (
-                    jnp.asarray(req_o.reshape(nch, chunk, R), dtype=dt),
-                    jnp.asarray(safe.reshape(nch, chunk, R), dtype=dt),
-                    jnp.asarray(big.reshape(nch, chunk, R), dtype=dt),
-                    jnp.asarray(crow_o.reshape(nch, chunk, Wp)),
-                )
-                self._preview_session = None if session is None else {
-                    "token": session, "shape": (C, W, R),
-                    "order": order_key, "order_arr": order,
-                    "pad": (Cp, Wp), "consts": consts,
-                }
+                ship = ((req_o.reshape(nch, chunk, R), dt),
+                        (safe.reshape(nch, chunk, R), dt),
+                        (big.reshape(nch, chunk, R), dt),
+                        (crow_o.reshape(nch, chunk, Wp), None))
             nch = Cp // chunk
             if demands is None:
                 d_o = np.zeros(Cp)
@@ -476,12 +528,18 @@ class JaxMatchmaker:
             fstack = np.zeros((N, R, Wp))
             for i, f in enumerate(frees):
                 fstack[i, :, :W] = np.asarray(f, dtype=np.float64).T
-            self._note_call("preview", (nch, Wp, N, self.dtype))
-            absorbed = self._fn_preview(
-                jnp.asarray(fstack, dtype=dt),
-                jnp.asarray(dd, dtype=dt),
-                *consts)
-            return order, Cp, np.asarray(absorbed)
+            with self._note_call("preview", (nch, Wp, N, self.dtype)) as rt:
+                if ship is not None:
+                    # the cohort constants ship once per session
+                    consts = tuple(rt.put(x, d) for x, d in ship)
+                    self._preview_session = None if session is None else {
+                        "token": session, "shape": (C, W, R),
+                        "order": order_key, "order_arr": order,
+                        "pad": (Cp, Wp), "consts": consts,
+                    }
+                absorbed = self._fn_preview(
+                    rt.put(fstack, dt), rt.put(dd, dt), *consts)
+                return order, Cp, rt.get(absorbed)
 
         with self._precision():
             order, Cp, absorbed = run()
@@ -510,7 +568,7 @@ class JaxMatchmaker:
          Cp, Wp) = self._prep(p)
         nch = Cp // chunk
         K = len(deltas)
-        self._note_call("match_cycles", (nch, Wp, K, self.dtype))
+        rt = self._note_call("match_cycles", (nch, Wp, K, self.dtype))
 
         arrivals = np.zeros((K, Cp))
         free_addT = np.zeros((K, R, Wp))
@@ -530,13 +588,13 @@ class JaxMatchmaker:
         self._require_exact("demand", d_o + np.cumsum(arrivals, axis=0),
                             _F32_DEMAND_LIMIT)
 
-        with self._precision():
+        with self._precision(), rt:
             takes_j, ran_j, free_per = self._run_cycles(
-                self.dtype, freeT, d_o, arrivals, free_addT,
+                rt.put, self.dtype, freeT, d_o, arrivals, free_addT,
                 budgets, req_o, safe, big, crow_o, nch, chunk, R, Wp)
-            takes_j = np.asarray(takes_j)
-            ran = np.asarray(ran_j)
-            free_per = np.asarray(free_per, dtype=np.float64)
+            takes_j = rt.get(takes_j)
+            ran = rt.get(ran_j)
+            free_per = rt.get(free_per, np.float64)
 
         plans: list[MatchPlan] = []
         for k in range(K):
@@ -548,30 +606,32 @@ class JaxMatchmaker:
                                    free_after=free_per[k][:, :W].T.copy()))
         return plans
 
-    def _run_cycles(self, dt, freeT, d_o, arrivals, free_addT, budgets,
-                    req_o, safe, big, crow_o, nch, chunk, R, Wp):
+    def _run_cycles(self, put, dt, freeT, d_o, arrivals, free_addT,
+                    budgets, req_o, safe, big, crow_o, nch, chunk, R, Wp):
         K = arrivals.shape[0]
         return self._fn_cycles(
-            jnp.asarray(freeT, dtype=dt),
-            jnp.asarray(d_o.reshape(nch, chunk), dtype=dt),
-            jnp.asarray(arrivals.reshape(K, nch, chunk), dtype=dt),
-            jnp.asarray(free_addT, dtype=dt),
-            jnp.asarray(budgets, dtype=dt),
-            jnp.asarray(req_o.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(safe.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(big.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(crow_o.reshape(nch, chunk, Wp)),   # uint8 mask
+            put(freeT, dt),
+            put(d_o.reshape(nch, chunk), dt),
+            put(arrivals.reshape(K, nch, chunk), dt),
+            put(free_addT, dt),
+            put(budgets, dt),
+            put(req_o.reshape(nch, chunk, R), dt),
+            put(safe.reshape(nch, chunk, R), dt),
+            put(big.reshape(nch, chunk, R), dt),
+            put(crow_o.reshape(nch, chunk, Wp)),   # uint8 mask
         )
 
-    def _run(self, dt, freeT, left, req_o, safe, big, d_o, crow_o,
+    def _run(self, put, dt, freeT, left, req_o, safe, big, d_o, crow_o,
              chunk_min, nch, chunk, R, Wp):
+        """The single-cycle dispatch; `put` copies one host array to the
+        device (and counts its bytes)."""
         return self._fn(
-            jnp.asarray(freeT, dtype=dt),
-            jnp.asarray(left, dtype=dt),
-            jnp.asarray(req_o.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(safe.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(big.reshape(nch, chunk, R), dtype=dt),
-            jnp.asarray(d_o.reshape(nch, chunk), dtype=dt),
-            jnp.asarray(crow_o.reshape(nch, chunk, Wp)),   # uint8 mask
-            jnp.asarray(chunk_min, dtype=dt),
+            put(freeT, dt),
+            put(left, dt),
+            put(req_o.reshape(nch, chunk, R), dt),
+            put(safe.reshape(nch, chunk, R), dt),
+            put(big.reshape(nch, chunk, R), dt),
+            put(d_o.reshape(nch, chunk), dt),
+            put(crow_o.reshape(nch, chunk, Wp)),   # uint8 mask
+            put(chunk_min, dt),
         )

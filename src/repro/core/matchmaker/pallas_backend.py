@@ -34,11 +34,13 @@ class PallasMatchmaker(JaxMatchmaker):
         super().__init__(dtype=dtype, chunk=chunk, unroll=unroll)
         self.interpret = interpret
 
-    def _run(self, dt, freeT, left, req_o, safe, big, d_o, crow_o,
+    def _run(self, put, dt, freeT, left, req_o, safe, big, d_o, crow_o,
              chunk_min, nch, chunk, R, Wp):
         # safe/big are re-derived from the requests inside the kernel
         return waterfill_ops.waterfill(
-            freeT, left, req_o.reshape(nch, chunk, R),
-            d_o.reshape(nch, chunk), crow_o.reshape(nch, chunk, Wp),
-            chunk_min, dtype=dt, interpret=self.interpret,
+            put(freeT, dt), put(left, dt),
+            put(req_o.reshape(nch, chunk, R), dt),
+            put(d_o.reshape(nch, chunk), dt),
+            put(crow_o.reshape(nch, chunk, Wp)), put(chunk_min, dt),
+            dtype=dt, interpret=self.interpret,
         )

@@ -56,6 +56,7 @@ from repro.core.groups import (
 )
 from repro.core.jobqueue import JobQueue
 from repro.core.worker import Collector, LRUCache, Worker
+from repro.observability import NO_SPAN
 
 
 @dataclasses.dataclass
@@ -265,10 +266,11 @@ class Provisioner:
             return cached
         self._c_preview_misses.value += 1
         prof = self.telemetry.profiler
-        t_p0 = prof.now() if prof is not None else 0.0
+        t_p0 = (prof.phase("repro.reconcile.preview") if prof is not None
+                else 0.0)
         previews = self.collector.preview(self.queues, now)
         if prof is not None:
-            self._preview_s += prof.now() - t_p0
+            self._preview_s += prof.phase() - t_p0
         self._preview_cache.put(key, previews)
         return previews
 
@@ -444,9 +446,14 @@ class Provisioner:
 
     # -- the loop body ----------------------------------------------------------
     def reconcile(self, now: float) -> ProvisionStats:
-        """One pass of the provisioning logic. Idempotent at fixed demand."""
-        stats = ProvisionStats()
+        """One pass of the provisioning logic. Idempotent at fixed demand.
+        With telemetry on, one `repro.reconcile` span."""
         prof = self.telemetry.profiler
+        with prof.reconcile_span() if prof is not None else NO_SPAN:
+            return self._reconcile(now, prof)
+
+    def _reconcile(self, now: float, prof) -> ProvisionStats:
+        stats = ProvisionStats()
         t_r0 = 0.0
         if prof is not None:
             t_r0 = prof.now()

@@ -74,7 +74,7 @@ from repro.core.stragglers import StragglerPolicy
 from repro.core.worker import (
     Collector, advance_workers, worker_from_state, worker_state,
 )
-from repro.observability import as_telemetry
+from repro.observability import NO_SPAN, as_telemetry
 
 # same-timestamp ordering, mirroring the seed's intra-tick sequence
 P_EXTERNAL = 0
@@ -223,7 +223,7 @@ class Simulation:
         # shell when telemetry is disabled beyond gauge registration)
         self.telemetry.attach_simulation(self)
 
-        self.loop = EventLoop()
+        self.loop = EventLoop(profiler=self.telemetry.profiler)
         self._advanced_until = 0.0
         self._external_pending = 0
         # live-fusion deferral horizon: while a negotiation backlog is
@@ -475,8 +475,11 @@ class Simulation:
         if t <= self._advanced_until:
             return
         dt = t - self._advanced_until
-        advance_workers(self.collector, self.pool_queue, self.cluster_view,
-                        self._advanced_until, dt)
+        prof = self.telemetry.profiler
+        with (prof.span("advance", "repro.advance") if prof is not None
+              else NO_SPAN):
+            advance_workers(self.collector, self.pool_queue,
+                            self.cluster_view, self._advanced_until, dt)
         self._advanced_until = t
 
     @classmethod
@@ -773,7 +776,7 @@ class Simulation:
             self.telemetry.load_state(tel_state)
 
         t = float(state["t"])
-        self.loop = EventLoop(t)
+        self.loop = EventLoop(t, profiler=self.telemetry.profiler)
         self.now = t
         self._advanced_until = t
         self._defer_until = -math.inf   # snapshots are quiescent

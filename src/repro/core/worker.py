@@ -74,7 +74,7 @@ from repro.core.matchmaker.base import (
     CycleDelta, match_cycles, sequential_preview_many,
 )
 from repro.core.matchmaker.base import RESOURCE_KEYS  # noqa: F401
-from repro.observability import as_telemetry
+from repro.observability import NO_SPAN, as_telemetry
 #   (re-exported: RESOURCE_KEYS moved to matchmaker.base with the
 #   protocol split; long-standing importers keep working)
 
@@ -332,6 +332,8 @@ class Collector:
         # and every timing site guards on that
         self.telemetry = as_telemetry(telemetry)
         self.profiler = self.telemetry.profiler
+        if self.profiler is not None and hasattr(self.matchmaker, "spans"):
+            self.matchmaker.spans = True
         # (job cohort, worker slot shape) -> bool; symmetric_match is a
         # pure function of the two ads, so entries never go stale on
         # their own — the LRU bound handles cohort churn, and
@@ -598,17 +600,21 @@ class Collector:
         owed schedd, then best-priority user, `quantum` claims per slice
         (see core/fairshare.py).  `max_submit` restricts the plain path
         to jobs submitted at or before that time (replay drivers hand
-        pre-loaded queues cycle timestamps).  Returns new claims."""
+        pre-loaded queues cycle timestamps).  Returns new claims.
+
+        With telemetry on, the whole call is one `repro.pass` span."""
         if hasattr(queues, "claim"):
             queues = [queues]
         else:
             queues = list(queues)
-        if accountant is None:
-            return self._plain_cycle(queues, now, max_submit=max_submit)
-        if max_submit is not None:
+        if accountant is not None and max_submit is not None:
             raise ValueError("max_submit is a plain-cycle knob; "
                              "fair-share cycles see the live queue")
-        return self._fairshare_cycle(queues, now, accountant, quantum)
+        prof = self.profiler
+        with prof.pass_span() if prof is not None else NO_SPAN:
+            if accountant is None:
+                return self._plain_cycle(queues, now, max_submit=max_submit)
+            return self._fairshare_cycle(queues, now, accountant, quantum)
 
     def negotiate_cycle(self, queues, now: float, *, accountant=None,
                         quantum: int = 1) -> int:
@@ -676,7 +682,8 @@ class Collector:
         batch is not provably fusable: a single staged cycle, workers
         changed mid-batch, quantity-reading expressions, or a cohort
         that fully drains mid-batch and re-arrives (its cross-cohort
-        FIFO key would re-seed — see jobqueue._cohort_min)."""
+        FIFO key would re-seed — see jobqueue._cohort_min).  With
+        telemetry on, a flush with cycles staged is one `repro.pass`."""
         if not self._staged_times:
             return 0
         times = self._staged_times
@@ -685,9 +692,13 @@ class Collector:
         self._staged_times = []
         self._staged_queues = None
         self._staged_fp = None
-
         prof = self.profiler
-        t_f0 = prof.now() if prof is not None else 0.0
+        with prof.pass_span() if prof is not None else NO_SPAN:
+            return self._flush(times, queues, fp0)
+
+    def _flush(self, times, queues, fp0) -> int:
+        prof = self.profiler
+        t_f0 = prof.phase("repro.pass.build") if prof is not None else 0.0
         workers = self.alive_workers(times[-1])
         rows = deltas = None
         t_m0 = t_a0 = t_f0
@@ -712,9 +723,12 @@ class Collector:
         if reason is None:
             problem = self._build_problem(rows, workers)
             problem.demand = np.zeros_like(problem.demand)
-            t_m0 = prof.now() if prof is not None else 0.0
+            t_m0 = prof.phase("repro.pass.match") if prof is not None else 0.0
             plans = match_cycles(self.matchmaker, problem, deltas)
-            t_a0 = prof.now() if prof is not None else 0.0
+            if prof is not None:
+                t_a0 = prof.phase("repro.pass.apply")
+                prof.note_device("cycle", getattr(self.matchmaker,
+                                                  "last_call", None))
             if self._reseed_hazard(plans, deltas):
                 reason = "reseed_hazard"
         if (reason is None and self.advance_hook is not None
@@ -742,7 +756,7 @@ class Collector:
             prof.record_cycle(
                 t=times[-1], kind="fused", w_start=t_f0,
                 build_s=t_m0 - t_f0, match_s=t_a0 - t_m0,
-                apply_s=prof.now() - t_a0, claims=claims,
+                apply_s=prof.phase() - t_a0, claims=claims,
                 backend=getattr(self.matchmaker, "name", ""),
                 compiled=None if lc is None else lc.get("compiled"),
                 fused_k=len(times))
@@ -892,7 +906,7 @@ class Collector:
                 self._c_noop_hits.value += 1
                 return 0
         prof = self.profiler
-        t_c0 = prof.now() if prof is not None else 0.0
+        t_c0 = prof.phase("repro.pass.build") if prof is not None else 0.0
         rows = []
         for qi, q in enumerate(queues):
             cohorts = []
@@ -909,6 +923,8 @@ class Collector:
             return 0
         reps = [next(iter(j.values())) for _qi, _k, j in rows]
         if self._quantity_sensitive(reps, workers):
+            if prof is not None:
+                prof.phase("repro.pass.legacy")
             free = np.stack([w.free_vec() for w in workers])
             total = 0
             for qi, q in enumerate(queues):
@@ -920,22 +936,23 @@ class Collector:
             if prof is not None:
                 prof.record_cycle(
                     t=now, kind="legacy", w_start=t_c0, build_s=0.0,
-                    match_s=prof.now() - t_c0, apply_s=0.0,
+                    match_s=prof.phase() - t_c0, apply_s=0.0,
                     claims=total, backend="legacy")
             return total
         problem = self._build_problem(rows, workers)
-        t_m0 = prof.now() if prof is not None else 0.0
+        t_m0 = prof.phase("repro.pass.match") if prof is not None else 0.0
         plan = self.matchmaker.match(problem)
-        t_a0 = prof.now() if prof is not None else 0.0
+        t_a0 = prof.phase("repro.pass.apply") if prof is not None else 0.0
         claims = self._apply_plan(queues, problem, plan, workers, now)
         if claims == 0 and memo_key is not None:
             self._noop_memo = memo_key
         if prof is not None:
             lc = getattr(self.matchmaker, "last_call", None)
+            prof.note_device("cycle", lc)
             prof.record_cycle(
                 t=now, kind="plain", w_start=t_c0,
                 build_s=t_m0 - t_c0, match_s=t_a0 - t_m0,
-                apply_s=prof.now() - t_a0, claims=claims,
+                apply_s=prof.phase() - t_a0, claims=claims,
                 backend=getattr(self.matchmaker, "name", ""),
                 compiled=None if lc is None else lc.get("compiled"))
         return claims
@@ -946,7 +963,7 @@ class Collector:
         if not workers:
             return 0
         prof = self.profiler
-        t_c0 = prof.now() if prof is not None else 0.0
+        t_c0 = prof.phase("repro.pass.build") if prof is not None else 0.0
         accountant.reset_cycle()
         names = [getattr(q, "name", f"schedd{i:02d}")
                  for i, q in enumerate(queues)]
@@ -967,6 +984,8 @@ class Collector:
         if self._quantity_sensitive(reps, workers):
             # legacy per-claim ladder: identical water-fill, with the
             # shrinking-offer expression rechecks the array path can't do
+            if prof is not None:
+                prof.phase("repro.pass.legacy")
             free = np.stack([w.free_vec() for w in workers])
             active: dict[tuple[int, str], list] = {}
             for (si, user), (qi, k, j) in zip(group_of, rows):
@@ -981,12 +1000,12 @@ class Collector:
             if prof is not None:
                 prof.record_cycle(
                     t=now, kind="legacy", w_start=t_c0, build_s=0.0,
-                    match_s=prof.now() - t_c0, apply_s=0.0,
+                    match_s=prof.phase() - t_c0, apply_s=0.0,
                     claims=total, backend="legacy")
             return total
 
         problem = self._build_problem(rows, workers)
-        t_b1 = prof.now() if prof is not None else 0.0
+        t_b1 = prof.phase() if prof is not None else 0.0
         match_s = apply_s = 0.0
         group_rows: dict[tuple[int, str], list[int]] = {}
         for c, g in enumerate(group_of):
@@ -1006,15 +1025,17 @@ class Collector:
 
             mask = np.zeros(C, dtype=bool)
             mask[group_rows[(si, user)]] = True
-            t_s0 = prof.now() if prof is not None else 0.0
+            t_s0 = prof.phase("repro.pass.match") if prof is not None else 0.0
             plan = self.matchmaker.match(problem, budget=quantum,
                                          active=mask)
-            t_s1 = prof.now() if prof is not None else 0.0
+            t_s1 = prof.phase("repro.pass.apply") if prof is not None else 0.0
             got = self._apply_plan(queues, problem, plan, workers, now,
                                    on_claim=observe)
             if prof is not None:
                 match_s += t_s1 - t_s0
-                apply_s += prof.now() - t_s1
+                apply_s += prof.phase() - t_s1
+                prof.note_device("cycle", getattr(self.matchmaker,
+                                                  "last_call", None))
             problem.free = plan.free_after
             problem.demand = problem.demand - plan.per_cohort()
             if got:
@@ -1154,6 +1175,7 @@ class Collector:
                 lc = getattr(self.matchmaker, "last_call", None)
                 if lc is not None and lc.get("compiled"):
                     prof.note_compile("preview")
+                prof.note_device("preview", lc)
         else:
             pers = sequential_preview_many(self.matchmaker, problem,
                                            cand)

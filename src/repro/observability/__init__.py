@@ -6,7 +6,9 @@ counters that tests and benchmarks read moved into it, so they must
 keep counting whether or not richer telemetry is on.  The `enabled`
 flag gates the two pieces with per-event cost: job-lifecycle span
 hooks (never installed when disabled) and the wall-clock cycle
-profiler (every site guards on `profiler is not None`).
+profiler with the program's spans — engine, pass, reconcile and device
+round trip, as `repro.*` TraceMes in a JAX profiler trace — (every
+site guards on `profiler is not None`).
 
     sim = Simulation(..., telemetry=True)
     sim.telemetry.prometheus_text()   # exposition, also GET /metrics.prom
@@ -25,12 +27,13 @@ from .registry import (Counter, Gauge, Histogram, MetricFamily,
                        MetricRegistry, SIM_SECONDS_BUCKETS,
                        WALL_SECONDS_BUCKETS)
 from .spans import LifecycleTracker
-from .profiler import CycleProfiler
+from .profiler import NO_SPAN, CycleProfiler, trace_me
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricFamily", "MetricRegistry",
     "SIM_SECONDS_BUCKETS", "WALL_SECONDS_BUCKETS",
-    "LifecycleTracker", "CycleProfiler", "Telemetry", "as_telemetry",
+    "LifecycleTracker", "CycleProfiler", "NO_SPAN", "Telemetry",
+    "as_telemetry", "trace_me",
 ]
 
 # pool gauges exported on scrape — the same series Recorder samples
@@ -62,7 +65,6 @@ class Telemetry:
         self._sim = None
         self._pool_gauges = None
         self._cache_g = None
-        self._mm_buckets_g = None
 
     # -- wiring --------------------------------------------------------------
     def attach_queue(self, q):
@@ -93,17 +95,6 @@ class Telemetry:
                     ("cache",))
                 for stat in ("hits", "misses", "entries")}
             self.registry.add_collect_hook(self._collect_caches)
-            # every distinct padding bucket the jitted backend has seen
-            # is one XLA trace; this counts ALL of them, including the
-            # ones the provisioner's preview path triggers outside any
-            # recorded negotiation cycle (which is why it can exceed
-            # the profiler's cycle-attributed jit_compiles)
-            self._mm_buckets_g = self.registry.gauge(
-                "repro_matchmaker_seen_buckets",
-                "Distinct padding buckets traced by the matchmaker "
-                "backend (== XLA compiles, preview included)",
-                ("backend",))
-            self.registry.add_collect_hook(self._collect_matchmaker)
         for q in sim.queues:
             self.attach_queue(q)
         self.bind_collector(sim.collector)
@@ -130,16 +121,6 @@ class Telemetry:
                 for b in sim.backends for n in b.cluster.nodes.values()))
         g["cost_rate"].value = float(
             sum(b.cost_rate() for b in sim.backends))
-
-    def _collect_matchmaker(self):
-        sim = self._sim
-        if sim is None:
-            return
-        mm = sim.collector.matchmaker
-        buckets = getattr(mm, "_seen_buckets", None)
-        if buckets is not None:
-            name = getattr(mm, "name", type(mm).__name__)
-            self._mm_buckets_g.labels(name).value = float(len(buckets))
 
     def _collect_caches(self):
         sim = self._sim

@@ -23,12 +23,20 @@ Pacing detail: the deadline for simulated time t is
 ``wall0 + (t - sim0)/speed``.  A late deadline (slow host, long
 injection) fires immediately — the driver catches up rather than
 stretching simulated cadences.
+
+Spans (with the simulation's telemetry on): the thread's running wall
+(`repro.driver.run`), its condition waits (`repro.driver.wait`) and each
+non-empty injection drain (`repro.driver.inject`), beside the engine's
+own event and advance spans — the `wait`, `inject` and `run` parts of
+`repro_engine_seconds_total`.
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Any, Callable
+
+from repro.observability import NO_SPAN
 
 
 class _Injection:
@@ -140,15 +148,28 @@ class WallClockDriver:
             pending, self._queue = self._queue, []
         if not pending:
             return False
-        self._settle()
-        for inj in pending:
-            try:
-                inj.result = inj.fn(self.sim)
-            except BaseException as e:  # propagate to the caller, not us
-                inj.error = e
-            finally:
-                inj.done.set()
+        prof = self.sim.telemetry.profiler
+        with (prof.span("inject", "repro.driver.inject") if prof is not None
+              else NO_SPAN):
+            self._settle()
+            for inj in pending:
+                try:
+                    inj.result = inj.fn(self.sim)
+                except BaseException as e:  # propagate to the caller
+                    inj.error = e
+                finally:
+                    inj.done.set()
         return True
+
+    def _wait(self, timeout: float):
+        """Sleep on the condition until woken, unless work is queued or
+        a stop was asked for."""
+        prof = self.sim.telemetry.profiler
+        with (prof.span("wait", "repro.driver.wait") if prof is not None
+              else NO_SPAN):
+            with self._cond:
+                if not self._queue and not self._stop:
+                    self._cond.wait(timeout)
 
     def _idle(self) -> bool:
         """Nothing left that time itself will change: every queue drained
@@ -159,6 +180,16 @@ class WallClockDriver:
         return sim.pool_queue.drained() and sim._external_pending == 0
 
     def _run(self):
+        prof = self.sim.telemetry.profiler
+        if prof is not None:
+            prof.run_begin()
+        try:
+            self._loop()
+        finally:
+            if prof is not None:
+                prof.run_end()
+
+    def _loop(self):
         wall0 = time.monotonic()
         sim0 = self.sim.now
         while True:
@@ -170,19 +201,14 @@ class WallClockDriver:
                 continue
             t = self.sim.loop.next_at()
             if t is None or (self.speed is None and self._idle()):
-                with self._cond:
-                    if not self._queue and not self._stop:
-                        self._cond.wait(self.idle_poll_s)
+                self._wait(self.idle_poll_s)
                 continue
             if self.speed is not None:
                 deadline = wall0 + (t - sim0) / self.speed
                 late = time.monotonic() >= deadline
                 if not late:
-                    with self._cond:
-                        if not self._queue and not self._stop:
-                            self._cond.wait(min(
-                                max(deadline - time.monotonic(), 0.0),
-                                0.25))
+                    self._wait(min(max(deadline - time.monotonic(), 0.0),
+                                   0.25))
                     continue   # re-check injections/stop before firing
             self._fire_group(t)
         # leave quiescent: finish the instant we stopped inside of
