@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import itertools
+import math
 from typing import Any, Callable, Iterable, Iterator
 
 from repro.core.classad import ClassAdExpr
@@ -70,6 +71,13 @@ class Job:
     # schedd the job came from (worker.py advance_workers)
     schedd: Any = dataclasses.field(default=None, repr=False,
                                     compare=False)
+    # run anchor while an event-engine worker runs the job
+    # (core/calendar.py): `remaining_s` is the work left at `run_t0`,
+    # and `t_finish` the time it completes at the worker's rate
+    run_t0: float = dataclasses.field(default=-1.0, repr=False,
+                                      compare=False)
+    t_finish: float = dataclasses.field(default=math.inf, repr=False,
+                                        compare=False)
 
     def __post_init__(self):
         if self.remaining_s < 0:

@@ -20,6 +20,9 @@ Between events, continuous state — running jobs, worker busy/alive time —
 is integrated lazily: before ANY event fires, `_advance_to(t)` advances
 the workers to exactly `t`, so a spot reclaim at t=12.5 sees job progress
 up to 12.5 and completions land at their exact finish times (C2 wakeups).
+The collector's `WorkerCalendar` (core/calendar.py) does this by visiting
+only the workers whose job finishes, whose boot lands or whose idle clock
+runs out — not every live worker per event.
 
 Compatibility: `tick_s`, `step()`, and `run(until)` keep their seed
 meaning (a step advances one tick's worth of events).  `engine="tick"`
@@ -60,6 +63,7 @@ import numpy as np
 from repro.core.backend import (
     FederatedClusterView, KubeBackend, build_backends,
 )
+from repro.core.calendar import WorkerCalendar
 from repro.core.cluster import KubeCluster, Node
 from repro.core.config import ProvisionerConfig
 from repro.core.events import EventLoop
@@ -237,6 +241,7 @@ class Simulation:
         self._timers: dict[str, Any] = {}
         self._backend_timers: dict[str, list] = {}
         if engine == "event":
+            self.collector.calendar = WorkerCalendar(self.collector)
             self.collector.advance_hook = self._advance_unchecked
             self._install_periodics()
 
@@ -342,10 +347,9 @@ class Simulation:
           * no worker's idle timeout can expire inside it (C2
             self-termination is a pool change).
 
-        Completion times are computed from `_advanced_until` — claim
-        remaining_s is exact as of the last advancement, which deferral
-        itself parks — so the check stays exact across chained
-        windows."""
+        Completion times are the calendar's finish times, keyed at claim
+        from each job's run anchor, so the check stays exact across
+        chained windows (deferral parks advancement itself)."""
         h = self._timers.get("negotiate")
         if h is None or h.cancelled:
             return False
@@ -353,26 +357,8 @@ class Simulation:
         if self.loop.has_event_before(t_next, P_NEGOTIATE):
             return False
         margin = 1e-6
-        horizon = t_next + margin
-        base = self._advanced_until
-        for w in self.collector.workers.values():
-            if w.terminated:
-                continue
-            if w.idle_timeout <= (t_next - now) + margin:
-                return False
-            if w.claimed:
-                for job in w.claimed.values():
-                    if job.work_fn is not None:
-                        return False
-                    rate = w.work_rate
-                    need = (job.remaining_s / rate if rate > 0
-                            else math.inf)
-                    if base + need <= horizon:
-                        return False
-            elif (not w.draining and w.idle_since >= 0
-                    and w.idle_since + w.idle_timeout <= horizon):
-                return False
-        return True
+        return self.collector.calendar.quiet(t_next - now, t_next + margin,
+                                             margin)
 
     def quiesce_negotiation(self) -> int:
         """Flush any deferred negotiation backlog NOW and bring worker
@@ -474,12 +460,11 @@ class Simulation:
     def _advance_unchecked(self, t: float):
         if t <= self._advanced_until:
             return
-        dt = t - self._advanced_until
         prof = self.telemetry.profiler
         with (prof.span("advance", "repro.advance") if prof is not None
               else NO_SPAN):
-            advance_workers(self.collector, self.pool_queue,
-                            self.cluster_view, self._advanced_until, dt)
+            self.collector.calendar.advance(self.pool_queue,
+                                            self.cluster_view, t)
         self._advanced_until = t
 
     @classmethod
@@ -515,7 +500,7 @@ class Simulation:
         running = {p.name for p in b.cluster.running_pods(owned)}
         for w in self.collector.workers.values():
             if w.pod_name in running:
-                w.draining = True
+                w.drain()
         if b.live_pods() == 0:
             self._detach_backend(b, now)
 
@@ -617,7 +602,7 @@ class Simulation:
         Simulation continues bit-identically to the uninterrupted run.
 
         Iteration orders are state here (advertise order drives
-        advance_workers, node order breaks best-fit ties, cohort order
+        worker advancement, node order breaks best-fit ties, cohort order
         drives negotiation FIFO) — every dict below is serialized in its
         live order and rebuilt by insertion, never recomputed or sorted.
 
@@ -726,11 +711,13 @@ class Simulation:
         if acc_state is not None:
             self.accountant.restore(acc_state)
 
-        self.all_workers = [worker_from_state(ws, jobs_by_jid)
+        t = float(state["t"])
+        self.all_workers = [worker_from_state(ws, jobs_by_jid, t)
                             for ws in state["workers"]]
         by_name = {w.name: w for w in self.all_workers}
         self.collector.workers = {n: by_name[n]
                                   for n in state["advertised"]}
+        self.collector.calendar.restore(self.collector.workers.values(), t)
 
         live = {b.name: b for b in self.backends}
         for bs in state["backends"]:
@@ -775,7 +762,6 @@ class Simulation:
         if tel_state is not None and self.telemetry.enabled:
             self.telemetry.load_state(tel_state)
 
-        t = float(state["t"])
         self.loop = EventLoop(t, profiler=self.telemetry.profiler)
         self.now = t
         self._advanced_until = t

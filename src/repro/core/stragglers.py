@@ -38,11 +38,14 @@ class StragglerPolicy:
             if age <= self.factor * job.runtime_s:
                 continue
             worker_name = job.claimed_by
-            queue.release(job.jid, now, preempted=True)
+            w = collector.workers.get(worker_name) if worker_name else None
+            if w is not None and job.jid in w.claimed:
+                w.release_claims(queue, now, [job.jid])
+            else:
+                queue.release(job.jid, now, preempted=True)
             n += 1
             self.rescheduled += 1
             if self.retire_worker and worker_name:
-                w = collector.workers.get(worker_name)
                 if w is not None:
                     kill_worker(collector, queue, worker_name, now)
                     if w.pod_name and cluster is not None:

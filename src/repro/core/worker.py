@@ -56,6 +56,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import itertools
+import math
 import warnings
 from collections import OrderedDict
 from typing import Any, Callable
@@ -165,16 +166,31 @@ class Worker:
     backend: str | None = None      # owning ScalingBackend (span labels)
 
     booted_at: float = -1.0                  # when startd became ready
-    idle_since: float = -1.0
+    #: C2 idle clock: `idle_since` (a property) is this, unless the
+    #: worker is idle on a calendar that keeps the clock of its whole
+    #: slot shape (core/calendar.py)
+    idle_own: float = dataclasses.field(default=-1.0, repr=False,
+                                        compare=False)
     claimed: dict[int, Job] = dataclasses.field(default_factory=dict)
     terminated: bool = False
     # a draining worker (its backend is being detached) takes NO new
     # claims — the negotiator/preview skip it via alive_workers — and
     # self-terminates as soon as its current claims complete
     draining: bool = False
-    # accounting
-    busy_s: float = 0.0
-    alive_s: float = 0.0
+    # accounting: seconds accrued up to `alive_t` / `busy_t`; the
+    # `alive_s` / `busy_s` properties add the time since, on the event
+    # engine's calendar (core/calendar.py), and are the plain accrued
+    # values for a worker no calendar keeps (tick engine, bare collector)
+    alive_acc: float = dataclasses.field(default=0.0, repr=False,
+                                         compare=False)
+    alive_t: float = dataclasses.field(default=0.0, repr=False,
+                                       compare=False)
+    busy_acc: float = dataclasses.field(default=0.0, repr=False,
+                                        compare=False)
+    busy_t: float = dataclasses.field(default=0.0, repr=False,
+                                      compare=False)
+    #: the `WorkerCalendar` keeping this worker (None: the eager walk)
+    cal: Any = dataclasses.field(default=None, repr=False, compare=False)
     _match_key: tuple | None = dataclasses.field(
         default=None, repr=False, compare=False)
     _res_vec: Any = dataclasses.field(default=None, repr=False,
@@ -188,6 +204,41 @@ class Worker:
     free_rev: int = dataclasses.field(default=0, repr=False, compare=False)
     _free_digest: Any = dataclasses.field(default=None, repr=False,
                                           compare=False)
+
+    @property
+    def idle_since(self) -> float:
+        cal = self.cal
+        return self.idle_own if cal is None else cal.idle_since(self)
+
+    @idle_since.setter
+    def idle_since(self, v: float):
+        self.idle_own = v
+
+    @property
+    def alive_s(self) -> float:
+        cal = self.cal
+        if cal is None or self.booted_at < 0 or cal.t <= self.alive_t:
+            return self.alive_acc
+        return self.alive_acc + (cal.t - self.alive_t)
+
+    @alive_s.setter
+    def alive_s(self, v: float):
+        self.alive_acc = v
+        if self.cal is not None:
+            self.alive_t = max(self.alive_t, self.cal.t)
+
+    @property
+    def busy_s(self) -> float:
+        cal = self.cal
+        if cal is None or not self.claimed or cal.t <= self.busy_t:
+            return self.busy_acc
+        return self.busy_acc + (cal.t - self.busy_t)
+
+    @busy_s.setter
+    def busy_s(self, v: float):
+        self.busy_acc = v
+        if self.cal is not None:
+            self.busy_t = max(self.busy_t, self.cal.t)
 
     def ready(self, now: float) -> bool:
         return self.booted_at >= 0 and now >= self.booted_at and not self.terminated
@@ -211,8 +262,45 @@ class Worker:
             self._used_vec = np.zeros(len(RESOURCE_KEYS), dtype=np.float64)
         self._used_vec += _job_req_vec(job)
         self.free_rev += 1
+        if self.cal is not None:
+            self.cal.on_claim(self, job)
+
+    def release_claims(self, queue, now: float, jids=None):
+        """Return claimed jobs (all, or those in `jids`) to IDLE as
+        preempted, each through its owning schedd (flocking).  A release
+        reads and rewrites the job's progress, so the calendar settles
+        it first and re-keys the jobs the worker still holds after."""
+        cal = self.cal
+        if cal is not None:
+            cal.settle(self)
+        for jid in list(self.claimed) if jids is None else jids:
+            job = self.claimed[jid]
+            (job.schedd or queue).release(jid, now, preempted=True)
+        if cal is not None:
+            cal.rekey(self)
+
+    def drain(self):
+        """Take no new claims and retire once the current ones complete
+        (the worker's backend is being detached)."""
+        self.draining = True
+        if self.cal is not None:
+            self.cal.wake(self)     # an idle one retires at the next boundary
 
     def drop_claim(self, jid: int) -> Job | None:
+        """Drop one claim from outside the advance (`condor_rm`): the
+        calendar settles the worker's clocks first and visits it at the
+        next boundary if it is left idle."""
+        cal = self.cal
+        if cal is not None:
+            cal.settle(self)
+        job = self.pop_claim(jid)
+        if cal is not None and job is not None and not self.claimed:
+            cal.wake(self)
+        return job
+
+    def pop_claim(self, jid: int) -> Job | None:
+        """Remove a claim, with no calendar bookkeeping (a completion
+        inside the advance, which keeps the clocks itself)."""
         job = self.claimed.pop(jid, None)
         if job is not None and self._used_vec is not None:
             self._used_vec -= _job_req_vec(job)
@@ -220,6 +308,8 @@ class Worker:
         return job
 
     def clear_claims(self):
+        if self.cal is not None:
+            self.cal.settle(self)
         self.claimed.clear()
         self._used_vec = None
         self.free_rev += 1
@@ -259,6 +349,24 @@ class Worker:
         return self._match_key
 
 
+def _set_work_rate(w: Worker, rate: float):
+    cal = w.cal
+    if cal is not None:
+        cal.settle(w)       # the progress so far ran at the old rate
+    w._work_rate = rate
+    if cal is not None:
+        cal.rekey(w)
+
+
+# set after the class, so that the dataclass keeps `work_rate=1.0` as an
+# __init__ field whose assignment goes through the setter
+Worker.work_rate = property(
+    lambda w: w._work_rate, _set_work_rate,
+    doc="Work done per second (<1.0 models a straggling node).  A "
+        "rewrite settles the running jobs at the old rate and re-keys "
+        "their finish times on the calendar.")
+
+
 # -- worker (de)serialization -------------------------------------------------
 def worker_state(w: Worker) -> dict:
     """JSON-safe snapshot: the START expression serializes as source
@@ -266,7 +374,13 @@ def worker_state(w: Worker) -> dict:
     order feeds completion order for same-instant finishes).  The cached
     resource vectors are NOT serialized — `worker_from_state` rebuilds
     `_used_vec` through `add_claim`, summing the same small integral
-    requests, so the float result is identical."""
+    requests, so the float result is identical.
+
+    Accounting is serialized in its lazy form, never materialized:
+    `alive_s` / `busy_s` are the seconds accrued up to `alive_t` /
+    `busy_t`, and `anchors` holds each claimed job's run anchor
+    `[run_t0, t_finish]` (its `remaining_s` is the work left at
+    `run_t0`; a finish time of None never comes)."""
     return {
         "name": w.name,
         "ad": dict(w.ad),
@@ -280,13 +394,21 @@ def worker_state(w: Worker) -> dict:
         "idle_since": w.idle_since,
         "terminated": w.terminated,
         "draining": w.draining,
-        "busy_s": w.busy_s,
-        "alive_s": w.alive_s,
+        "busy_s": w.busy_acc,
+        "busy_t": w.busy_t,
+        "alive_s": w.alive_acc,
+        "alive_t": w.alive_t,
         "claimed": list(w.claimed.keys()),
+        "anchors": [[j.run_t0, j.t_finish if j.t_finish < math.inf
+                     else None] for j in w.claimed.values()],
     }
 
 
-def worker_from_state(state: dict, jobs_by_jid: dict[int, Job]) -> Worker:
+def worker_from_state(state: dict, jobs_by_jid: dict[int, Job],
+                      t: float = 0.0) -> Worker:
+    """Rebuild a worker from `worker_state` output taken at time `t`.  A
+    snapshot without the lazy fields (older ones) holds accrued values
+    as of `t` and claims whose remaining work is as of `t`."""
     w = Worker(
         name=state["name"],
         ad=dict(state["ad"]),
@@ -301,10 +423,22 @@ def worker_from_state(state: dict, jobs_by_jid: dict[int, Job]) -> Worker:
     w.idle_since = float(state.get("idle_since", -1.0))
     w.terminated = bool(state.get("terminated", False))
     w.draining = bool(state.get("draining", False))
-    w.busy_s = float(state.get("busy_s", 0.0))
-    w.alive_s = float(state.get("alive_s", 0.0))
-    for jid in state.get("claimed", []):
-        w.add_claim(jobs_by_jid[int(jid)])
+    w.busy_acc = float(state.get("busy_s", 0.0))
+    w.busy_t = float(state.get("busy_t", t))
+    w.alive_acc = float(state.get("alive_s", 0.0))
+    w.alive_t = float(state.get("alive_t", max(t, w.booted_at)))
+    anchors = state.get("anchors")
+    for i, jid in enumerate(state.get("claimed", [])):
+        job = jobs_by_jid[int(jid)]
+        w.add_claim(job)
+        if anchors is None:
+            job.run_t0 = t
+            job.t_finish = (t + job.remaining_s / w.work_rate
+                            if w.work_rate > 0 else math.inf)
+        else:
+            run_t0, t_finish = anchors[i]
+            job.run_t0 = float(run_t0)
+            job.t_finish = math.inf if t_finish is None else float(t_finish)
     return w
 
 
@@ -344,6 +478,10 @@ class Collector:
         # changes; a pool of identical idle workers polls once per
         # version, not once per worker per event
         self._poll_cache = LRUCache(self.MATCH_CACHE_MAX)
+        #: the event engine's lazy worker advancement (core/calendar.py,
+        #: installed by `Simulation`); None keeps every worker on the
+        #: eager walk, `advance_workers`
+        self.calendar = None
         # -- fused negotiation staging (stage_cycle / flush_staged) ----------
         #: how many consecutive cycles to accumulate before flushing
         #: through the backend's fused multi-cycle jit (1 = stage
@@ -409,9 +547,13 @@ class Collector:
 
     def advertise(self, worker: Worker):
         self.workers[worker.name] = worker
+        if self.calendar is not None:
+            self.calendar.register(worker)
 
     def invalidate(self, name: str):
-        self.workers.pop(name, None)
+        w = self.workers.pop(name, None)
+        if w is not None and w.cal is not None:
+            w.cal.unregister(w)
 
     def invalidate_cohort(self, cohort_key=None) -> int:
         """Explicitly drop memoized ClassAd verdicts: all of them, or
@@ -426,6 +568,8 @@ class Collector:
         # poll verdicts aggregate over cohorts; any cohort change can
         # flip them regardless of the idle_version guard
         self._poll_cache.invalidate()
+        if self.calendar is not None:
+            self.calendar.forget_verdicts()
         return n
 
     def alive_workers(self, now: float) -> list[Worker]:
@@ -1376,7 +1520,10 @@ def advance_workers(
     `scan_matches=True` / `exact_completions=False` together reproduce
     the seed tick loop verbatim (per-job C2 idle poll, completions
     quantized to now+dt, no mid-interval boot credit) — the tick-engine
-    baseline; the defaults are the event engine's exact semantics."""
+    baseline; the defaults are the event engine's exact semantics,
+    which the event engine reaches through `WorkerCalendar`
+    (core/calendar.py) without walking every worker, and which
+    tests/test_lazy_advance.py holds it to."""
     t1 = now + dt
     terminated = []
     for w in list(collector.workers.values()):
@@ -1393,6 +1540,8 @@ def advance_workers(
             seg0, seg = now, dt
         w.alive_s += seg
         idle_from = seg0         # idleness cannot predate the boot
+        # (twin: WorkerCalendar._visit runs this body with exact
+        # completions on the event engine's lazy clocks; change both)
         if w.claimed:
             busy_until = seg0
             for jid, job in list(w.claimed.items()):
@@ -1469,8 +1618,7 @@ def kill_worker(collector: Collector, queue: JobQueue, worker_name: str,
     w = collector.workers.get(worker_name)
     if w is None:
         return
-    for jid, job in list(w.claimed.items()):
-        (job.schedd or queue).release(jid, now, preempted=True)
+    w.release_claims(queue, now)
     w.clear_claims()
     w.terminated = True
     collector.invalidate(worker_name)

@@ -64,6 +64,20 @@ def trace_me(name: str, **meta):
 NO_SPAN = contextlib.nullcontext()
 
 
+def advance_counters(registry: MetricRegistry):
+    """The event engine's advancement counters: workers visited, and
+    advance calls (core/calendar.py counts them; cycle records carry
+    both)."""
+    return (registry.counter(
+                "repro_advance_workers_touched_total",
+                "Workers the event engine visited while advancing "
+                "continuous state before events"),
+            registry.counter(
+                "repro_advance_calls_total",
+                "Advances of the event engine's continuous state (one "
+                "before each event time)"))
+
+
 class _Span:
     """Context manager over `CycleProfiler.enter`/`exit`."""
 
@@ -126,6 +140,7 @@ class CycleProfiler:
             "repro_device_transfer_bytes_total",
             "Bytes copied between host and device by matchmaker calls, "
             "by entry path and direction", ("path", "direction"))
+        self._advance = advance_counters(registry)
         self.cycle_log_max = int(cycle_log_max)
         self.cycles: deque = deque(maxlen=self.cycle_log_max)
         self.reconciles: deque = deque(maxlen=self.cycle_log_max)
@@ -288,8 +303,9 @@ class CycleProfiler:
         """One negotiation cycle.  `w_start` is the absolute
         perf_counter at cycle start; durations are wall seconds.  The
         record also carries the open pass's `pass_id`, the round trip
-        and bytes of the device calls since the pass's last record, and
-        `engine_s`, a snapshot of `engine_seconds()`."""
+        and bytes of the device calls since the pass's last record,
+        `engine_s`, a snapshot of `engine_seconds()`, and beside it the
+        advancement counters `advance_touched` and `advance_calls`."""
         self.phase_h.labels("build").observe(build_s)
         self.phase_h.labels("match").observe(match_s)
         self.phase_h.labels("apply").observe(apply_s)
@@ -303,7 +319,9 @@ class CycleProfiler:
                "claims": claims, "backend": backend,
                "pass_id": self.pass_id, "roundtrip_s": rt,
                "h2d_bytes": h2d, "d2h_bytes": d2h,
-               "engine_s": self.engine_seconds()}
+               "engine_s": self.engine_seconds(),
+               "advance_touched": self._advance[0].value,
+               "advance_calls": self._advance[1].value}
         top = self._stack[-1] if self._stack else None
         if top is not None and top[0] == "pass" and top[3] is not None:
             top[3].set_metadata(kind=kind)
