@@ -3,12 +3,14 @@ rebuilt from the seeded trace (`bench.passes`), and the sampled device
 calls against the plain water-fill (`bench.reference`).
 
 Each number compared is a count of disagreements, so every limit is 0:
-  claims_differing         over the sampled plain passes, jobs whose
+  claims_differing         over the sampled passes of every kind
+                           (plain, flocking, fair-share), jobs whose
                            claim (worker, or none) differs from the
-                           pass `bench.passes` rebuilds from the trace
-                           and the job events: cohorts, their order,
-                           demand, requests, compatibility and free
-                           capacity all come from there
+                           pass `bench.passes` rebuilds from the trace,
+                           the job events and the deployment's
+                           statement: cohorts, their order, demand,
+                           requests, compatibility, free capacity and
+                           the fair-share slices all come from there
   plan_cells_differing     takes and free-after cells of the sampled
                            ``match``/``match_cycles`` answers that
                            differ from `reference.waterfill` on the
@@ -16,7 +18,7 @@ Each number compared is a count of disagreements, so every limit is 0:
   preview_cells_differing  per-cohort absorbed counts of the sampled
                            previews that differ from the reference on
                            the same problem
-  checked_passes_missing   1 when the window held no plain pass to check
+  checked_passes_missing   1 when the window held no pass to check
 """
 from __future__ import annotations
 
@@ -33,19 +35,24 @@ def _plan_diff(takes, free_after, ref_takes, ref_free) -> int:
             + int(np.count_nonzero(np.asarray(free_after) != ref_free)))
 
 
-def compare(probe, records: list[dict]) -> tuple[dict, int]:
+def compare(probe, records: list[dict],
+            deployment: passes.Deployment | None = None
+            ) -> tuple[dict, int, int]:
     """({name: (value, limit)} for every number compared, the number of
-    sampled passes and calls whose answer disagreed).  `records` is the
-    run's trace, drawn again from the seed."""
+    sampled passes and calls whose answer disagreed, the number of
+    sampled passes with a choice that tied to rounding).  `records` is
+    the run's trace, drawn again from the seed, and `deployment` what
+    the cell's configuration states of its passes."""
     plan = preview = bad = 0
-    per_pass = passes.claims_differing(records, probe.log,
-                                       probe.pass_samples.items)
+    per_pass, ties = passes.claims_differing(
+        records, probe.log, probe.pass_samples.items,
+        record_of=probe.record_of, deployment=deployment)
     claims = sum(per_pass)
     bad += sum(c > 0 for c in per_pass)
     for item in probe.match_samples.items:
         p = item["problem"]
         ref_takes, ref_free = reference.waterfill(
-            p.requests, p.demand, p.order, p.compat, p.free,
+            p.requests, item["demand"], p.order, p.compat, item["free"],
             budget=item["budget"], active=item["active"])
         d = _plan_diff(item["takes"], item["free_after"], ref_takes, ref_free)
         plan += d
@@ -79,12 +86,16 @@ def compare(probe, records: list[dict]) -> tuple[dict, int]:
         "plan_cells_differing": (plan, 0),
         "preview_cells_differing": (preview, 0),
         "checked_passes_missing": (int(not probe.pass_samples.items), 0),
-    }, int(bad)
+    }, int(bad), ties
 
 
-def sampled(probe) -> dict:
+def sampled(probe, near_ties: int) -> dict:
+    kinds: dict[str, int] = {}
+    for item in probe.pass_samples.items:
+        kinds[item["kind"]] = kinds.get(item["kind"], 0) + 1
     return {"passes": len(probe.pass_samples.items),
             "passes_seen": probe.pass_samples.seen,
+            "pass_kinds": kinds, "near_tie_passes": near_ties,
             "match": len(probe.match_samples.items),
             "match_seen": probe.match_samples.seen,
             "match_cycles": len(probe.cycle_samples.items),

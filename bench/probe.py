@@ -5,12 +5,16 @@ Nothing here changes what the pool decides.  `Probe` wraps, on one
 
   * ``Collector.run_cycle`` / ``flush_staged`` — one host-clock span per
     negotiation pass (build, match and apply, or one fused flush), and a
-    seeded sample of the plain passes with the claims each made;
+    seeded sample of the passes with the claims each made, of one kind:
+    ``plain`` (one schedd), ``flocking`` (several, in flocking order) or
+    ``fairshare`` (several, under the fair-share accountant);
   * ``Provisioner.reconcile`` — one host-clock span per reconcile;
   * the queues' hooks — every job entering or leaving the idle set,
-    every claim and every completion, from the first submission on, so
-    that the check can rebuild a sampled pass's idle jobs and each
-    worker's running jobs without the program's own bookkeeping;
+    every claim and every completion, each with its simulated time, from
+    the first submission on, so that the check can rebuild a sampled
+    pass's idle jobs, each worker's running jobs and each submitter's
+    decayed usage without the program's own bookkeeping, and the trace
+    record each job was submitted from;
   * the collector's matchmaker — a forwarding wrapper that keeps a
     seeded sample of the device calls (problem in, answer out) for the
     correctness check and, in traced runs, names each call in the
@@ -32,8 +36,21 @@ from bench import roofline
 SAMPLE_PAIRS = 150_000_000
 SAMPLE_MIN, SAMPLE_MAX = 4, 64
 
-#: kinds of job events in `Probe.log`
+#: kinds of job events in `Probe.log`: (IDLE_IN, job, t),
+#: (IDLE_OUT, job, t), (CLAIM, job, worker, t), (DONE, job, t)
 IDLE_IN, IDLE_OUT, CLAIM, DONE = range(4)
+
+
+def record_key(schedd, arrival_s, runtime_s, user, group) -> tuple:
+    """What tells a trace record from the others, as its job shows it."""
+    return (schedd, float(arrival_s), float(runtime_s), user, group)
+
+
+def n_idle_cohorts(queues) -> int:
+    """Idle cohorts over one queue or a flocking list of them."""
+    if hasattr(queues, "idle_cohorts"):
+        queues = [queues]
+    return sum(sum(1 for _ in q.idle_cohorts()) for q in queues)
 
 
 class Reservoir:
@@ -129,6 +146,10 @@ class Probe:
         self.claims: list[tuple] = []    # (jid, worker, sim t, cohort key)
         #: every job event since the first submission, in order
         self.log: list[tuple] = []
+        #: {job: index of its trace record}, filled as each job enters
+        #: the queue; `expect` says which records are coming
+        self.record_of: dict[int, int] = {}
+        self._expected: dict[tuple, list[int]] = {}
         #: traced device calls of the window: (kind, problem, answers)
         self.device_calls: list[tuple] = []
         rng = np.random.default_rng([seed, 0xC0FFEE])
@@ -154,10 +175,22 @@ class Probe:
         prov.reconcile = reconcile
         log = self.log
         for q in self.sim.queues:
-            q.add_idle_hook(lambda job, d: log.append(
-                (IDLE_IN if d > 0 else IDLE_OUT, job.jid)))
+            q.add_idle_hook(self._on_idle)
             q.add_claim_hook(self._on_claim)
-            q.add_complete_hook(lambda job: log.append((DONE, job.jid)))
+            q.add_release_hook(self._on_release)
+            q.add_complete_hook(lambda job: log.append(
+                (DONE, job.jid, job.completed_at)))
+
+    def expect(self, records: list[dict], default_schedd: str):
+        """The trace about to be submitted: each job that enters a queue
+        for the first time is matched to its record by the record's own
+        fields (at trace times the service hands back no job ids)."""
+        for i, r in enumerate(records):
+            self._expected.setdefault(record_key(
+                r.get("schedd") or default_schedd, r["arrival_s"],
+                r["runtime_s"], r["user"], r["group"]), []).append(i)
+        for waiting in self._expected.values():
+            waiting.reverse()               # popped from the end: FIFO
 
     def _wrap_pass(self, col, name: str):
         inner = getattr(col, name)
@@ -168,8 +201,8 @@ class Probe:
                 # the warm-up covers what a device pass could be given
                 now = a[1] if len(a) > 1 else kw["now"]
                 self.shapes["pass"].add((
-                    sum(1 for _ in (a[0] if a else kw["queues"])
-                        .idle_cohorts()), len(col.alive_workers(now))))
+                    n_idle_cohorts(a[0] if a else kw["queues"]),
+                    len(col.alive_workers(now))))
             pos = len(self.log)
             n0 = len(self.claims)
             t0 = time.perf_counter()
@@ -178,32 +211,59 @@ class Probe:
             t1 = time.perf_counter()
             if self.recording:
                 self.passes.append((t0, t1))
-                # a plain pass over one queue: its claims can be rebuilt
-                # from the job events before it
-                if (name == "run_cycle" and kw.get("accountant") is None
-                        and kw.get("max_submit") is None):
+                # a whole pass over the live queues: its claims can be
+                # rebuilt from the job events before it
+                if name == "run_cycle" and kw.get("max_submit") is None:
                     now = a[1] if len(a) > 1 else kw["now"]
-                    self.offer_pass(col, a[0] if a else kw["queues"], now,
-                                    pos, n0)
+                    queues = a[0] if a else kw["queues"]
+                    kind = ("fairshare" if kw.get("accountant") is not None
+                            else "plain" if hasattr(queues, "idle_cohorts")
+                            else "flocking")
+                    self.offer_pass(col, queues, kind, now, pos, n0)
             return out
 
         setattr(col, name, run)
 
+    def _on_idle(self, job, d):
+        if d < 0:
+            # a claim (or a removal): the claim hook stamps its time
+            self.log.append((IDLE_OUT, job.jid, None))
+        elif job.claimed_by is not None:
+            # a release: the release hook stamps its time
+            self.log.append((IDLE_IN, job.jid, None))
+        else:
+            self.log.append((IDLE_IN, job.jid, job.submitted_at))
+            waiting = self._expected.get(record_key(
+                job.schedd.name, job.submitted_at, job.runtime_s,
+                job.ad.get("user"), job.ad.get("accounting_group")))
+            if waiting:
+                self.record_of[job.jid] = waiting.pop()
+
+    def _stamp(self, jid, now):
+        """Give the event just logged for `jid` its time, where the idle
+        hook could not know it."""
+        ev = self.log[-1] if self.log else None
+        if ev is not None and ev[1] == jid and len(ev) == 3 and ev[2] is None:
+            self.log[-1] = (ev[0], jid, now)
+
     def _on_claim(self, job, now):
-        self.log.append((CLAIM, job.jid, job.claimed_by))
+        self._stamp(job.jid, now)
+        self.log.append((CLAIM, job.jid, job.claimed_by, now))
         if self.recording:
             self.claims.append((job.jid, job.claimed_by, now,
                                 job.cohort_key))
 
+    def _on_release(self, job, now):
+        self._stamp(job.jid, now)
+
     # -- sampling ----------------------------------------------------------
-    def offer_pass(self, col, queue, now, pos, n0):
+    def offer_pass(self, col, queues, kind, now, pos, n0):
         workers = col.alive_workers(now)
         slot = self.pass_samples.slot(
-            lambda: len(workers) * max(1, sum(1 for _ in
-                                              queue.idle_cohorts())))
+            lambda: len(workers) * max(1, n_idle_cohorts(queues)))
         if slot is not None:
             self.pass_samples.items[slot] = {
-                "pos": pos, "now": now,
+                "kind": kind, "pos": pos, "now": now,
                 "workers": [(w.name, w.ad, w.start_expr.src)
                             for w in workers],
                 "claims": [(jid, w) for jid, w, _t, _k in self.claims[n0:]]}
@@ -211,8 +271,12 @@ class Probe:
     def offer_match(self, problem, plan, budget, active):
         slot = self.match_samples.slot(problem.compat.size)
         if slot is not None:
+            # a fair-share pass hands the next slice the same problem
+            # with its demand and free capacity replaced: keep this
+            # call's own
             self.match_samples.items[slot] = {
-                "problem": problem, "takes": plan.takes,
+                "problem": problem, "demand": problem.demand,
+                "free": problem.free, "takes": plan.takes,
                 "free_after": plan.free_after, "budget": budget,
                 "active": active}
 

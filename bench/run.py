@@ -10,7 +10,8 @@ settings) and a traffic mix (``bench/traffic/<traffic>.json``).  A run:
      exits nonzero before any result;
   2. set-up: builds a `PoolService` from the deployment (``speed=None``:
      the served path, sprinting), schedules the seeded trace through
-     `PoolClient.submit(..., at_trace_times=True)`, runs the warm-up
+     `PoolClient.submit(..., at_trace_times=True)`, each record to the
+     schedd it names (or the default one), runs the warm-up
      span of simulated time, then calls the matchmaker once on every
      padding bucket that the warm-up's second half touched, with its
      calls or with the sizes of its passes (and one cohort chunk either
@@ -147,6 +148,15 @@ def warm_buckets(mm, shapes: dict):
                     mm.preview_many(p, [p.free])
 
 
+def route(records: list[dict]) -> dict:
+    """The records by the schedd each names (None: the default one), in
+    trace order within each."""
+    out: dict = {}
+    for r in records:
+        out.setdefault(r.get("schedd"), []).append(r)
+    return out
+
+
 def window_cycles(prof, before):
     """Profiler cycle records appended after `before` (the last record
     at the window's start)."""
@@ -171,7 +181,7 @@ def run_cell(catalog: Catalog, workload: str, seed: int, seconds: float,
     from repro.compile_cache import use_compile_cache
     from repro.service.pool import PoolClient, PoolService
 
-    from bench import check
+    from bench import check, passes
     from bench.probe import Probe
 
     t_start = time.perf_counter() if t_start is None else t_start
@@ -186,6 +196,7 @@ def run_cell(catalog: Catalog, workload: str, seed: int, seconds: float,
     marks = {"start": t_start, "imported": time.perf_counter()}
     cell = catalog.cell(workload)
     config = catalog.config(cell["config"])
+    deployment = passes.Deployment(config)
     traffic = dict(catalog.traffic(cell["traffic"]), **(overrides or {}))
     generator = catalog.generator(traffic["shape"])
     records = generator.generate(traffic, seed)
@@ -197,7 +208,10 @@ def run_cell(catalog: Catalog, workload: str, seed: int, seconds: float,
     if wrap_matchmaker is not None:
         col.matchmaker = wrap_matchmaker(col.matchmaker)
     probe = Probe(svc, seed=seed, traced=traced)
-    PoolClient(svc).submit(records, at_trace_times=True, at=0.0)
+    probe.expect(records, svc.sim.queue.name)
+    client = PoolClient(svc)
+    for schedd, recs in route(records).items():
+        client.submit(recs, schedd=schedd, at_trace_times=True, at=0.0)
     del records
     marks["submitted"] = time.perf_counter()
 
@@ -290,7 +304,7 @@ def run_cell(catalog: Catalog, workload: str, seed: int, seconds: float,
     probe.device_calls = []
     records = generator.generate(traffic, seed)
     marks["measured"] = time.perf_counter()
-    numbers, failed = check.compare(probe, records)
+    numbers, failed, ties = check.compare(probe, records, deployment)
     marks["checked"] = time.perf_counter()
     if horizon > 0:
         # a window that ran past the trace's end measured an emptying pool
@@ -309,7 +323,7 @@ def run_cell(catalog: Catalog, workload: str, seed: int, seconds: float,
     out["window"] = {"sim_s": [sim_t0, sim_t1], "passes": len(probe.passes),
                      "reconciles": len(probe.reconciles),
                      "claims": len(probe.claims), "sampled":
-                     check.sampled(probe)}
+                     check.sampled(probe, ties)}
     stamps = list(marks.items())
     out["timing_s"] = {b: tb - ta for (_a, ta), (b, tb) in
                        zip(stamps[:-1], stamps[1:])}

@@ -36,9 +36,9 @@ def _rec(t, user="u0", cpus=1):
 
 def test_rebuilt_pass_serves_the_first_idle_cohort_first_and_deals_fifo():
     recs = [_rec(0.0, "a"), _rec(1.0, "b"), _rec(2.0, "a"), _rec(3.0, "a")]
-    log = [(IDLE_IN, j) for j in range(4)]
+    log = [(IDLE_IN, j, float(j)) for j in range(4)]
     # job 0 ran and left; cohort "a" keeps its first mark while job 2 waits
-    log += [(IDLE_OUT, 0), (CLAIM, 0, "w0")]
+    log += [(IDLE_OUT, 0, 3.0), (CLAIM, 0, "w0", 3.0)]
     replay = passes.Replay(recs)
     replay.advance(log, len(log))
     workers = [("w0", {"cpus": 2, "memory": 8.0, "disk": 16.0}, ""),
@@ -49,15 +49,15 @@ def test_rebuilt_pass_serves_the_first_idle_cohort_first_and_deals_fifo():
     assert got == {2: "w0", 3: "w1"}
     assert passes.claims_differing(recs, log, [
         {"pos": len(log), "workers": workers,
-         "claims": [(2, "w0"), (3, "w1")]}]) == [0]
+         "claims": [(2, "w0"), (3, "w1")]}]) == ([0], 0)
     assert passes.claims_differing(recs, log, [
         {"pos": len(log), "workers": workers,
-         "claims": [(1, "w0"), (2, "w1")]}]) == [3]
+         "claims": [(1, "w0"), (2, "w1")]}]) == ([3], 0)
 
 
 def test_quantity_reading_requirements_are_checked_before_every_claim():
     recs = [dict(_rec(0.0), requirements="memory >= 4") for _ in range(3)]
-    log = [(IDLE_IN, j) for j in range(3)]
+    log = [(IDLE_IN, j, 0.0) for j in range(3)]
     replay = passes.Replay(recs)
     replay.advance(log, len(log))
     workers = [("w0", {"cpus": 8, "memory": 7.0, "disk": 64.0}, "")]

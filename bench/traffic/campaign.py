@@ -26,8 +26,18 @@ Parameters (a traffic file's keys):
                           disk_gb, requirements, attrs, runtime}], with
                           runtime {dist: lognormal, median_s, sigma,
                           min_s} or {dist: pareto, min_s, alpha, cap_s}
+  communities             optional [{name, users}]: the users split
+                          among schedds, ``users`` each, summing to
+                          n_users; which users a community holds, and
+                          which community each burst belongs to, are
+                          drawn from structure_seed, and the seed then
+                          reorders kinds, users and runtimes only within
+                          a community, so every seed gives each schedd
+                          the same work
 
-Records are dicts in the pool's trace-record form.
+Records are dicts in the pool's trace-record form; with communities
+each also names its schedd (``schedd``), and without them the records
+are those the generator gave before communities existed, byte for byte.
 """
 from __future__ import annotations
 
@@ -68,6 +78,38 @@ def _runtimes(rng, runtime: dict, n: int) -> np.ndarray:
     raise ValueError(f"unknown runtime dist {runtime['dist']!r}")
 
 
+def _communities(params: dict, n_users: int):
+    """(community of each user, the users of each community) or None.
+    Drawn from their own stream of ``structure_seed``, so that the other
+    draws stay those of the same mix without communities."""
+    comms = params.get("communities")
+    if not comms:
+        return None
+    sizes = [int(c["users"]) for c in comms]
+    if min(sizes) < 1 or sum(sizes) != n_users:
+        raise ValueError(f"communities hold {sizes} users; they must "
+                         f"partition all {n_users}, each at least one")
+    perm = np.random.default_rng([params["structure_seed"], 1]).permutation(
+        n_users)
+    members = np.split(perm, np.cumsum(sizes)[:-1])
+    of = np.empty(n_users, dtype=np.int64)
+    for ci, users in enumerate(members):
+        of[users] = ci
+    return of, members
+
+
+def _reorder(rng, values: np.ndarray, groups) -> np.ndarray:
+    """`values` permuted by the run's seed, within each group where
+    `groups` (one label per value) is given."""
+    if groups is None:
+        return rng.permutation(values)
+    out = values.copy()
+    for g in np.unique(groups):
+        idx = np.nonzero(groups == g)[0]
+        out[idx] = rng.permutation(values[idx])
+    return out
+
+
 def generate(params: dict, seed: int) -> list[dict]:
     rng = np.random.default_rng(seed)
     src = np.random.default_rng(params["structure_seed"])
@@ -79,16 +121,20 @@ def generate(params: dict, seed: int) -> list[dict]:
     n_burst_total = int(total * params["burst_frac"]) if n_bursts > 0 else 0
     n_base = total - n_burst_total
     width = len(str(n_users - 1))
+    comms = _communities(params, n_users)
+    # a burst's community, when there are communities to belong to
+    csrc = np.random.default_rng([params["structure_seed"], 2])
 
     rows: list[tuple[float, int, int]] = []   # (arrival, kind, user)
     base_t = _arrivals(src, n_base, horizon)
     base_kind = _kinds(src, kinds, n_base)
     base_user = _users(src, n_base, n_users, params["zipf_s"])
     reorder = set(params.get("seed_reorders", ()))
+    base_comm = None if comms is None else comms[0][base_user]
     if "kind" in reorder:
-        base_kind = rng.permutation(base_kind)
+        base_kind = _reorder(rng, base_kind, base_comm)
     if "user" in reorder:
-        base_user = rng.permutation(base_user)
+        base_user = _reorder(rng, base_user, base_comm)
     rows.extend(zip(base_t.tolist(), base_kind.tolist(), base_user.tolist()))
     if n_burst_total > 0:
         sizes = src.multinomial(n_burst_total,
@@ -98,8 +144,12 @@ def generate(params: dict, seed: int) -> list[dict]:
             if size <= 0:
                 continue
             kind = int(_kinds(src, kinds, 1)[0])
-            user = int((rng if "user" in reorder else src)
-                       .integers(0, n_users))
+            if comms is None or "user" not in reorder:
+                user = int((rng if "user" in reorder else src)
+                           .integers(0, n_users))
+            else:
+                users = comms[1][comms[0][csrc.integers(0, n_users)]]
+                user = int(users[rng.integers(0, len(users))])
             ts = np.clip(center + params["burst_width_s"]
                          * src.standard_normal(size),
                          0.0, max(horizon - 1e-3, 0.0))
@@ -107,19 +157,25 @@ def generate(params: dict, seed: int) -> list[dict]:
     rows.sort(key=lambda r: r[0])
 
     order_kinds = np.asarray([r[1] for r in rows], dtype=np.int64)
+    row_comm = None if comms is None else comms[0][
+        np.asarray([r[2] for r in rows], dtype=np.int64)]
     runtimes = np.empty(len(rows))
     for ki, kind in enumerate(kinds):
         idx = np.nonzero(order_kinds == ki)[0]
+        if row_comm is not None:
+            # each community's jobs of a kind take their own run of draws
+            idx = idx[np.argsort(row_comm[idx], kind="stable")]
         if len(idx):
             draws = _runtimes(src, kind["runtime"], len(idx))
             if "runtime" in reorder:
-                draws = rng.permutation(draws)
+                draws = _reorder(rng, draws, None if row_comm is None
+                                 else row_comm[idx])
             runtimes[idx] = draws
 
     out = []
     for (t, ki, u), rt in zip(rows, runtimes.tolist()):
         k = kinds[ki]
-        out.append({
+        rec = {
             "arrival_s": round(float(t), 3),
             "runtime_s": round(float(rt), 3),
             "cpus": int(k["cpus"]),
@@ -130,5 +186,8 @@ def generate(params: dict, seed: int) -> list[dict]:
             "group": k["name"],
             "user": f"user{u:0{width}d}",
             "attrs": dict(sorted(k["attrs"].items())),
-        })
+        }
+        if comms is not None:
+            rec["schedd"] = params["communities"][comms[0][u]]["name"]
+        out.append(rec)
     return out
