@@ -20,7 +20,8 @@ from repro.core.worker import (
 )
 from repro.core.groups import GroupSignature, group_jobs, signature_of
 from repro.core.config import (
-    BackendConfig, ProvisionerConfig, dump_ini, load_ini, PAPER_EXAMPLE_INI,
+    BackendConfig, FairshareConfig, ProvisionerConfig, dump_ini, load_ini,
+    PAPER_EXAMPLE_INI,
 )
 from repro.core.backend import (
     FederatedClusterView, KubeBackend, PodSpec, ROUTING_POLICIES,

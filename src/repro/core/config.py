@@ -21,6 +21,15 @@ The [provision] section adds the scaling knobs (filter, limits, timing) and
 central-manager address arrive via k8s secret/env (§3); here they are just
 fields.
 
+Fair-share (HTCondor's user priorities and group quotas, "User
+Priorities and Negotiation" in its manual): an optional ``[fairshare]``
+section states the accountant's terms — ``half_life_s``
+(PRIORITY_HALFLIFE), ``base_priority`` (the floor of a real priority),
+``default_factor`` (DEFAULT_PRIO_FACTOR), ``quantum`` (claims per
+fair-share slice), ``quotas_dict`` (schedd:quota) and ``factors_dict``
+(user:factor).  A simulation built with ``fairshare=True`` takes them;
+without the section it keeps the accountant's defaults.
+
 Federation extension (backend API): any number of `[backend:<name>]`
 sections declare resource providers — each with its own node template,
 limits, priority class, and cost — consumed by
@@ -97,6 +106,34 @@ class BackendConfig:
 
 
 @dataclasses.dataclass
+class FairshareConfig:
+    """The `[fairshare]` INI section: the accountant's terms.  Every
+    number must be positive and `quantum` a whole number."""
+    half_life_s: float = 86400.0
+    base_priority: float = 0.5
+    default_factor: float = 1.0
+    quantum: int = 1
+    quotas: dict[str, float] = dataclasses.field(default_factory=dict)
+    factors: dict[str, float] = dataclasses.field(default_factory=dict)
+
+    def __post_init__(self):
+        terms = {"half_life_s": self.half_life_s,
+                 "base_priority": self.base_priority,
+                 "default_factor": self.default_factor,
+                 "quantum": self.quantum}
+        terms.update({f"quota {k}": v for k, v in self.quotas.items()})
+        terms.update({f"factor {k}": v for k, v in self.factors.items()})
+        for name, v in terms.items():
+            if not v > 0:
+                raise ValueError(
+                    f"[fairshare] {name} must be positive, got {v}")
+        if self.quantum != int(self.quantum):
+            raise ValueError(f"[fairshare] quantum must be a whole "
+                             f"number, got {self.quantum}")
+        self.quantum = int(self.quantum)
+
+
+@dataclasses.dataclass
 class ProvisionerConfig:
     # [condor]
     central_manager: str = "cm.local"
@@ -120,6 +157,9 @@ class ProvisionerConfig:
 
     # [backend:<name>] sections (empty ⇒ single default backend)
     backends: tuple[BackendConfig, ...] = ()
+
+    # [fairshare] (None ⇒ the accountant's defaults)
+    fairshare: FairshareConfig | None = None
 
     # [k8s] (Fig 1)
     k8s_domain: str = "nrp-nautilus.io"
@@ -186,6 +226,9 @@ def load_ini(text: str) -> ProvisionerConfig:
             cfg.storage = {k: str(v) for k, v in
                            _parse_dict(sec["storage_dict"]).items()}
 
+    if "fairshare" in cp:
+        cfg.fairshare = _load_fairshare_section(cp["fairshare"])
+
     backends = []
     for section in cp.sections():
         if not section.startswith("backend:"):
@@ -194,6 +237,18 @@ def load_ini(text: str) -> ProvisionerConfig:
             section.split(":", 1)[1], cp[section]))
     cfg.backends = tuple(backends)
     return cfg
+
+
+def _load_fairshare_section(sec) -> FairshareConfig:
+    fs = FairshareConfig()
+    quantum = sec.getfloat("quantum", float(fs.quantum))
+    return FairshareConfig(
+        half_life_s=sec.getfloat("half_life_s", fs.half_life_s),
+        base_priority=sec.getfloat("base_priority", fs.base_priority),
+        default_factor=sec.getfloat("default_factor", fs.default_factor),
+        quantum=quantum,
+        quotas=_parse_num_dict(sec.get("quotas_dict", "")),
+        factors=_parse_num_dict(sec.get("factors_dict", "")))
 
 
 def _load_backend_section(name: str, sec) -> BackendConfig:
@@ -266,6 +321,20 @@ def dump_ini(cfg: ProvisionerConfig) -> str:
         lines.append(f"envs_dict={_fmt_dict(cfg.envs)}")
     if cfg.storage:
         lines.append(f"storage_dict={_fmt_dict(cfg.storage)}")
+    fs = cfg.fairshare
+    if fs is not None:
+        lines += [
+            "",
+            "[fairshare]",
+            f"half_life_s={fs.half_life_s!r}",
+            f"base_priority={fs.base_priority!r}",
+            f"default_factor={fs.default_factor!r}",
+            f"quantum={fs.quantum}",
+        ]
+        if fs.quotas:
+            lines.append(f"quotas_dict={_fmt_dict(fs.quotas)}")
+        if fs.factors:
+            lines.append(f"factors_dict={_fmt_dict(fs.factors)}")
     for bc in cfg.backends:
         lines += [
             "",

@@ -45,10 +45,18 @@ import dataclasses
 import math
 from typing import Any, Iterable
 
+import numpy as np
+
 from repro.core.jobqueue import DEFAULT_USER, USER_ATTR, user_of  # noqa: F401
 #   (re-exported: the accountant's callers key ledgers by user_of(job))
 
 LN2 = math.log(2.0)
+
+#: virtual cores `Accountant.pass_keys` charges to read each key's rise:
+#: a power of two, so the division by it is exact, and large, so the
+#: rounding of the key it reads is a small share of the rise
+PROBE_CORES = 2.0 ** 20
+_PROBE = "\x00probe"      # the other side of a probe charge: no real name
 
 
 def job_cores(job) -> float:
@@ -79,30 +87,43 @@ class UsageLedger:
         self._rate: dict[str, float] = {}    # running cores
         self._t: dict[str, float] = {}       # last settle time per key
 
-    def _settle(self, key: str, now: float):
+    def _settle(self, key: str, now: float) -> float:
+        """Bring `key` to `now`.  Where it already stands at a later
+        time (an observation logged out of time order), it stays there,
+        and the return is the decay from `now` to that time: what a
+        change made at `now` has decayed by since (1.0 otherwise)."""
         t0 = self._t.get(key)
         if t0 is None:
             self._t[key] = now
-            return
+            return 1.0
         dt = now - t0
         if dt <= 0:
-            return
+            return 0.5 ** (-dt / self.half_life_s)
         d = 0.5 ** (dt / self.half_life_s)
         u = self._usage.get(key, 0.0)
         r = self._rate.get(key, 0.0)
         # closed form of du/dt = r - (ln2/hl) u over [t0, now]
         self._usage[key] = u * d + r * self.tau * (1.0 - d)
         self._t[key] = now
+        return 1.0
 
     def add_rate(self, key: str, delta_cores: float, now: float):
-        """A job started (+cores) or stopped (-cores) at `now`."""
-        self._settle(key, now)
+        """A job started (+cores) or stopped (-cores) at `now`.  A change
+        logged after the key was settled at a later time (the engine
+        reports some completions after later events) is back-dated: the
+        key is given what the change accrued from `now` to that time, so
+        the ledger reads as if the changes had come in time order."""
+        d = self._settle(key, now)
+        if d < 1.0:
+            self._usage[key] = (self._usage.get(key, 0.0)
+                                + delta_cores * self.tau * (1.0 - d))
         self._rate[key] = self._rate.get(key, 0.0) + delta_cores
 
     def charge(self, key: str, core_seconds: float, now: float):
-        """One-shot usage charge (tests / imported accounting state)."""
-        self._settle(key, now)
-        self._usage[key] = self._usage.get(key, 0.0) + core_seconds
+        """One-shot usage charge (tests / imported accounting state); one
+        dated before the key's last settle has decayed since."""
+        d = self._settle(key, now)
+        self._usage[key] = self._usage.get(key, 0.0) + core_seconds * d
 
     def usage(self, key: str, now: float) -> float:
         """Decayed core-seconds of accumulated usage at `now`."""
@@ -259,6 +280,34 @@ class Accountant:
         cores = (self.groups.effective_cores(schedd, now)
                  + self._vgroup.get(schedd, 0.0))
         return cores / self.quota(schedd)
+
+    def pass_keys(self, schedds: list[str], users: list[str], now: float):
+        """The keys of one negotiation pass as affine functions of the
+        cores charged this pass: ``(owed, owed_slope, prio, prio_slope)``
+        arrays over `schedds` and `users`, where a schedd's key is
+        ``owed + owed_slope * v`` and a user's ``prio + prio_slope * v``
+        (`matchmaker.base.FairshareEntries`).  Both are read from
+        `group_owed` and `effective_priority` before and after a virtual
+        charge of `PROBE_CORES` (`charge_virtual`), so that these queries
+        stay the one definition of a key; they are affine in the virtual
+        cores, as HTCondor's are.  Starts and ends the cycle with no
+        virtual charge."""
+        self.reset_cycle()
+
+        def read():
+            return (np.array([self.group_owed(s, now) for s in schedds]),
+                    np.array([self.effective_priority(u, now)
+                              for u in users]))
+
+        owed, prio = read()
+        for s in schedds:
+            self.charge_virtual(s, _PROBE, PROBE_CORES)
+        for u in users:
+            self.charge_virtual(_PROBE, u, PROBE_CORES)
+        owed1, prio1 = read()
+        self.reset_cycle()
+        return (owed, (owed1 - owed) / PROBE_CORES,
+                prio, (prio1 - prio) / PROBE_CORES)
 
     # -- persistence ---------------------------------------------------------
     def state_dict(self) -> dict[str, Any]:

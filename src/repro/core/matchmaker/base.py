@@ -24,6 +24,22 @@ cohort fits anywhere, bounded by demand.  ``budget`` caps total claims
 (fair-share hands out quantum-sized slices); ``active`` restricts the
 pass to a subset of cohorts (one (schedd, user) group per slice) without
 re-building the problem.
+
+Fair-share passes (``match_fairshare``): the hierarchical slice loop of
+HTCondor's negotiator over one problem.  Each slice goes to the schedd
+with the lowest key, there to the user with the lowest key, and runs a
+`match` restricted to that (schedd, user) entry's cohorts with budget
+``quantum``; its claims charge their cores to both keys; an entry that
+claims fewer than ``quantum`` retires.  Keys are affine in the cores
+charged this pass (`FairshareEntries`).  Keys within `KEY_RTOL` of the
+lowest, relative, are one key, and ties go to the schedd's flocking
+index, then the user's name: the keys come from a ledger settled in
+floating point at every observation, so two submitters with one history
+can read a last digit apart, and a backend that carries its keys in
+another precision must still order them alike.  `sequential_match_fairshare`
+is the reference: one `match` call per slice.  A backend may fuse the
+loop (`JaxMatchmaker.match_fairshare`: one device program per pass) and
+must return the same plan.
 """
 from __future__ import annotations
 
@@ -59,9 +75,9 @@ class MatchProblem:
     """A pure matchmaking instance: C cohorts × W workers × R resources.
 
     Built once per negotiation cycle by `Collector._build_problem`;
-    `free` and `demand` are threaded through successive fair-share
-    slices (assign ``free = plan.free_after`` and decrement ``demand``
-    by the per-cohort take sums between `match` calls).
+    the fair-share reference (`sequential_match_fairshare`) hands each
+    slice a copy with the free capacity and demand the slices before it
+    left.
     """
     keys: list          # per cohort: (queue index, cohort key)
     requests: np.ndarray      # (C, R) float64 — per-job request vector
@@ -114,6 +130,51 @@ class CycleDelta:
     arrivals: np.ndarray            # (C,) int64 — demand added
     free_add: np.ndarray | None = None   # (W, R) float64 — capacity back
     budget: int | None = None       # per-cycle claim cap
+
+
+#: fair-share keys this close to the lowest, relative, tie (module
+#: docstring).  Far below the rounding any accounting question could
+#: care about, and far above both float64's rounding of the keys (about
+#: 1e-16) and the error of a double-float32 key (about 1e-13, `jax_backend`).
+KEY_RTOL = 1e-10
+
+
+@dataclasses.dataclass
+class FairshareEntries:
+    """One fair-share pass's (schedd, user) entries over a `MatchProblem`.
+
+    A schedd's key is ``owed[s] + owed_slope[s] * v`` and a user's
+    ``prio[u] + prio_slope[u] * v``, where ``v`` is the cores charged to
+    it so far this pass: HTCondor's decayed group cores over quota, and
+    factor x (base + decayed cores), as the accountant reads them.
+    Schedds are numbered by flocking order and users by name among the
+    pass's own, so that a tie goes to the lower number."""
+    cohort_entry: np.ndarray   # (C,) int64 — each cohort row's entry
+    cohort_cores: np.ndarray   # (C,) float64 — cores charged per claim
+    schedd: np.ndarray         # (E,) int64 — each entry's schedd
+    user: np.ndarray           # (E,) int64 — each entry's user
+    owed: np.ndarray           # (S,) float64 — schedd keys at v = 0
+    owed_slope: np.ndarray     # (S,) float64 — their rise per core
+    prio: np.ndarray           # (U,) float64 — user keys at v = 0
+    prio_slope: np.ndarray     # (U,) float64 — their rise per core
+
+    @property
+    def n_entries(self) -> int:
+        return int(self.schedd.shape[0])
+
+
+@dataclasses.dataclass
+class FairsharePlan(MatchPlan):
+    """A fair-share pass's plan: the takes of all its slices summed,
+    the free capacity after the last, how many slices it ran, and how
+    many matchmaker calls that took.
+
+    Summed takes lose nothing: free capacity only shrinks within a pass,
+    so each cohort's first worker with room never moves back, and dealing
+    its oldest jobs to its workers in index order (`Collector._apply_plan`)
+    gives each job the worker its slice gave it."""
+    slices: int = 0
+    calls: int = 0
 
 
 @runtime_checkable
@@ -195,6 +256,71 @@ def preview_many(mm: "Matchmaker", problem: MatchProblem,
     if fused is not None:
         return fused(problem, frees, demands)
     return sequential_preview_many(mm, problem, frees, demands)
+
+
+def _lowest_keys(keys: np.ndarray, live: np.ndarray) -> np.ndarray:
+    """Mask of the live keys within `KEY_RTOL` of the lowest live key."""
+    low = keys[live].min()
+    return live & (keys <= low + KEY_RTOL * abs(low))
+
+
+def _next_entry(entries: FairshareEntries, v_schedd: np.ndarray,
+               v_user: np.ndarray, live: np.ndarray) -> int:
+    """The entry the next slice goes to: the lowest schedd key among
+    schedds with a live entry, then that schedd's lowest user key."""
+    s_live = np.zeros(len(entries.owed), dtype=bool)
+    s_live[entries.schedd[live]] = True
+    s_key = entries.owed + entries.owed_slope * v_schedd
+    si = int(np.argmax(_lowest_keys(s_key, s_live)))
+    mine = live & (entries.schedd == si)
+    u_key = (entries.prio + entries.prio_slope * v_user)[entries.user]
+    cand = _lowest_keys(u_key, mine)
+    return int(np.argmin(np.where(cand, entries.user, np.iinfo(np.int64).max)))
+
+
+def sequential_match_fairshare(mm: "Matchmaker", problem: MatchProblem,
+                               entries: FairshareEntries,
+                               quantum: int) -> FairsharePlan:
+    """The fair-share pass's reference semantics (module docstring): one
+    `match` call per slice, with the demand and free capacity the slices
+    before it left."""
+    demand = np.asarray(problem.demand, dtype=np.int64).copy()
+    free = np.array(problem.free, dtype=np.float64, copy=True)
+    takes = np.zeros(problem.compat.shape, dtype=np.int64)
+    v_schedd = np.zeros(len(entries.owed))
+    v_user = np.zeros(len(entries.prio))
+    live = np.ones(entries.n_entries, dtype=bool)
+    slices = 0
+    while live.any():
+        e = _next_entry(entries, v_schedd, v_user, live)
+        sub = dataclasses.replace(problem, demand=demand, free=free)
+        plan = mm.match(sub, budget=quantum,
+                        active=entries.cohort_entry == e)
+        got = plan.per_cohort()
+        takes += plan.takes
+        free = plan.free_after
+        demand = demand - got
+        cores = float(got @ entries.cohort_cores)
+        v_schedd[entries.schedd[e]] += cores
+        v_user[entries.user[e]] += cores
+        if got.sum() < quantum:
+            # demand or matching capacity exhausted for this entry —
+            # neither can grow within the pass, so it retires
+            live[e] = False
+        slices += 1
+    return FairsharePlan(takes=takes, free_after=free, slices=slices,
+                         calls=slices)
+
+
+def match_fairshare(mm: "Matchmaker", problem: MatchProblem,
+                    entries: FairshareEntries,
+                    quantum: int) -> FairsharePlan:
+    """Dispatch a fair-share pass to the backend's fused implementation
+    when it has one, else the sequential reference."""
+    fused = getattr(mm, "match_fairshare", None)
+    if fused is not None:
+        return fused(problem, entries, quantum)
+    return sequential_match_fairshare(mm, problem, entries, quantum)
 
 
 def cohort_fits(free: np.ndarray, want: np.ndarray,

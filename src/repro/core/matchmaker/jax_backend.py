@@ -25,9 +25,11 @@ Scale tricks (the ROADMAP's array-compiled matchmaking item):
   * **donated free buffer** — the (R, W) carry is donated to the jit,
     avoiding a defensive copy per cycle.
 
-The three device programs compile as ``jit_waterfill_match``,
-``jit_waterfill_cycles`` and ``jit_waterfill_preview``: stable names in
-a profiler trace.  Each call's ``last_call`` says its padding bucket,
+The four device programs compile as ``jit_waterfill_match``,
+``jit_waterfill_cycles``, ``jit_waterfill_preview`` and
+``jit_waterfill_fairshare`` (a whole fair-share pass, every slice in one
+``lax.while_loop``): stable names in a profiler trace.  Each call's
+``last_call`` says its padding bucket,
 whether the bucket was fresh, its round trip (``repro.device.roundtrip``:
 first host-to-device copy to the answer on the host) and the bytes of
 the padded arrays sent and fetched.
@@ -49,7 +51,8 @@ from functools import lru_cache, partial
 import numpy as np
 
 from repro.core.matchmaker.base import (
-    FIT_EPS, RESOURCE_KEYS, CycleDelta, MatchPlan, MatchProblem,
+    FIT_EPS, KEY_RTOL, RESOURCE_KEYS, CycleDelta, FairshareEntries,
+    FairsharePlan, MatchPlan, MatchProblem,
 )
 from repro.observability import trace_me
 
@@ -60,6 +63,7 @@ from jax import lax
 _ZERO_WANT_BIG = 1e15     # ratio offset for zero-request resource lanes
 _W_LANES = 128            # worker-axis padding bucket
 _PREVIEW_LANES = 512      # preview lane floor (one trace per replay)
+_FS_ENTRY_LANES = 32      # fair-share entry-axis floor (power-of-two buckets)
 
 # float32's exactness domain.  Quantities below 2**24 are exact
 # integers, and so is every free/want floor and free - want*take built
@@ -268,6 +272,151 @@ def _build_cycles_scan(chunk: int, unroll: int):
     return jax.jit(waterfill_cycles)
 
 
+# -- fair-share keys as double-float pairs -----------------------------------
+#
+# A fair-share key is affine in the cores charged this pass, a + b*v
+# (base.FairshareEntries), and keys a last digit apart must order as the
+# host's float64 orders them up to base.KEY_RTOL.  float32 alone resolves
+# 6e-8, so on a TPU each key is carried as an unevaluated sum hi + lo of
+# two float32s.  The host hands the device a as (a_hi, a_lo), within
+# 2**-48 |a|, and b as b1 + b2 + b3, where b1 and b2 hold 12 significant
+# bits each and b3 the rest rounded to float32 (within 2**-47 |b|).  The
+# device splits v (a whole number below 2**24 in float32) as
+# vh + vl with vh = 4096 floor(v / 4096) and vl < 4096, so vh and vl hold
+# 12 significant bits: the four products of vh, vl with b1, b2 are exact
+# (24 bits), vh*b3 and vl*b3 are within 2**-24 of terms below 2**-23 |b v|,
+# and each is added in by an error-free sum (`_two_sum`, adds and
+# subtracts only, so a fused multiply-add cannot change it) into (hi, lo)
+# renormalised after each.  b3 is below 2**-22 |b|, so the two inexact
+# products lose 2**-45 |b v| together, and each of the six additions at
+# most 2**-47 of the running sum: for a >= 0, b v >= 0 (every key of a
+# sound accountant) the key is within 2**-43 (1.2e-13) relative, and the
+# pairwise difference of two keys adds 2**-24 of itself: far below
+# KEY_RTOL (1e-10), so the device ties and orders keys as float64 does.
+# In float64 the host hands (a, 0) and (b, 0, 0), and the same code
+# computes a + b v to float64's rounding.
+
+def _two_sum(a, b):
+    """Knuth's error-free sum: s + e == a + b exactly."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _dd_add(hi, lo, x):
+    """Add `x` to the double-float hi + lo, renormalised."""
+    s, e = _two_sum(hi, x)
+    e = e + lo
+    hi = s + e
+    return hi, e - (hi - s)
+
+
+def _dd_affine(k, v):
+    """a + b*v as a double-float, from k = (a_hi, a_lo, b1, b2, b3)
+    (each (E,)) and v (E,) (see above)."""
+    a_hi, a_lo, b1, b2, b3 = k
+    vh = jnp.floor(v * (1.0 / 4096.0)) * 4096.0
+    vl = v - vh
+    hi, lo = a_hi, a_lo
+    for t in (vh * b1, vl * b1, vh * b2, vl * b2, vh * b3, vl * b3):
+        hi, lo = _dd_add(hi, lo, t)
+    return hi, lo
+
+
+def _top12(x: np.ndarray) -> np.ndarray:
+    """x rounded to float32 and cut to its 12 leading significant bits."""
+    bits = np.asarray(x, dtype=np.float32).view(np.uint32)
+    return (bits & np.uint32(0xFFFFF000)).view(np.float32).astype(np.float64)
+
+
+def key_terms(a, b, dtype: str, n: int) -> np.ndarray:
+    """(5, n) rows a_hi, a_lo, b1, b2, b3 of the keys a + b*v, padded
+    with zeros to `n` (see above)."""
+    a = np.asarray(a, dtype=np.float64)
+    b = np.asarray(b, dtype=np.float64)
+    out = np.zeros((5, n))
+    if dtype == "float64":
+        out[0, :len(a)] = a
+        out[2, :len(b)] = b
+        return out
+    a_hi = a.astype(np.float32).astype(np.float64)
+    b1 = _top12(b)
+    b2 = _top12(b - b1)
+    out[0, :len(a)] = a_hi
+    out[1, :len(a)] = (a - a_hi).astype(np.float32)
+    out[2, :len(b)] = b1
+    out[3, :len(b)] = b2
+    out[4, :len(b)] = (b - b1 - b2).astype(np.float32)
+    return out
+
+
+def _lowest(hi, lo, ok):
+    """Mask of the `ok` keys within KEY_RTOL of the lowest `ok` key:
+    each i whose difference to every ok j is at most KEY_RTOL |key_j|."""
+    d = (hi[:, None] - hi[None, :]) + (lo[:, None] - lo[None, :])
+    within = (d <= KEY_RTOL * jnp.abs(hi)[None, :]) | ~ok[None, :]
+    return ok & jnp.all(within, axis=1)
+
+
+@lru_cache(maxsize=None)
+def _build_fairshare(chunk: int, unroll: int):
+    """The fused fair-share pass: a `lax.while_loop` over slices, each
+    one (1) the schedd with the lowest key among those with a live
+    entry, ties to the lower index, (2) its live entry whose user has
+    the lowest key, ties to the lower name rank, (3) the chunked
+    water-fill of `_make_steps` over that entry's cohorts with budget
+    `quantum`, its drain guard re-derived from the live demand as in
+    `_build_cycles_scan`, (4) the slice's cores charged to both keys,
+    and (5) the entry retired if it claimed fewer than `quantum`.  Free
+    capacity, demand, the summed takes and both virtual ledgers stay on
+    the device; the pass returns its summed takes, its free capacity
+    and its slice count (`base.sequential_match_fairshare` is the
+    reference it reproduces)."""
+    _inner, chunk_step = _make_steps(unroll)
+
+    def waterfill_fairshare(freeT, d_s, want_s, safe_s, big_s, crow_s,
+                            cent_s, cores_s, ent_s, ent_u, live, s_keys,
+                            u_keys, quantum, max_slices):
+        dt = freeT.dtype
+        E = live.shape[0]
+        slots = jnp.arange(E)
+
+        def body(c):
+            freeT, d_s, takes, v_s, v_u, live, n = c
+            s_ok = jnp.any((ent_s[None, :] == slots[:, None])
+                           & live[None, :], axis=1)
+            si = jnp.argmax(_lowest(*_dd_affine(s_keys, v_s), s_ok))
+            mine = live & (ent_s == si)
+            u_hi, u_lo = _dd_affine(u_keys, v_u)
+            cand = _lowest(u_hi[ent_u], u_lo[ent_u], mine)
+            e = jnp.argmin(jnp.where(cand, ent_u, E))
+            d_e = jnp.where(cent_s == e, d_s, 0.0)
+            minreq = jnp.min(
+                jnp.where((d_e > 0)[..., None], want_s, jnp.inf), axis=1)
+            (freeT, _left), (tk, _ran) = lax.scan(
+                chunk_step, (freeT, quantum),
+                (want_s, safe_s, big_s, d_e, crow_s, minreq))
+            got_c = jnp.sum(tk, axis=2).astype(dt)
+            got = jnp.sum(got_c)
+            cores = jnp.sum(got_c * cores_s)
+            live = live.at[e].set(got >= quantum)
+            return (freeT, d_s - got_c, takes + tk,
+                    v_s.at[ent_s[e]].add(cores),
+                    v_u.at[ent_u[e]].add(cores), live, n + 1)
+
+        def cond(c):
+            return jnp.any(c[5]) & (c[6] < max_slices)
+
+        init = (freeT, d_s, jnp.zeros(crow_s.shape, jnp.int32),
+                jnp.zeros(E, dt), jnp.zeros(E, dt), live,
+                jnp.zeros((), jnp.int32))
+        freeT, _d, takes, _vs, _vu, _live, n = lax.while_loop(
+            cond, body, init)
+        return takes, freeT, n
+
+    return jax.jit(waterfill_fairshare, donate_argnums=(0,))
+
+
 class _RoundTrip:
     """One matchmaker call's device round trip, from its first
     host-to-device copy (`put`) to its answer on the host (`get`):
@@ -323,6 +472,12 @@ class JaxMatchmaker:
         self.unroll = int(unroll)
         self._fn = _build_scan(self.chunk, self.unroll)
         self._fn_cycles = _build_cycles_scan(self.chunk, self.unroll)
+        self._fn_fairshare = _build_fairshare(self.chunk, self.unroll)
+        #: set by a fair-share pool: each fresh `match` bucket compiles
+        #: the fair-share program of its (C, W) bucket beside it, for
+        #: every entry bucket seen, so that warming `match` warms both
+        self.fairshare_twins = False
+        self._fs_entry_buckets = {_FS_ENTRY_LANES}
         # unroll=1 for preview: the preview path is compile-bound, not
         # dispatch-bound (a handful of memo-missing calls per replay,
         # each on a fresh lane bucket as the pool grows), and a rolled
@@ -444,6 +599,9 @@ class JaxMatchmaker:
         if budget is not None:
             self._require_exact("budget", budget)
         left = math.inf if budget is None else float(budget)
+        if self.fairshare_twins:
+            for Ep in sorted(self._fs_entry_buckets):
+                self._warm_fairshare(nch, Wp, Ep)
         rt = self._note_call("match", (nch, Wp, self.dtype))
 
         with self._precision(), rt:
@@ -462,6 +620,99 @@ class JaxMatchmaker:
         takes[order[live]] = takes_flat[live, :W]
         return MatchPlan(takes=takes[:C],
                          free_after=freeT_j[:, :W].T.copy())
+
+    def match_fairshare(self, p: MatchProblem, entries: FairshareEntries,
+                        quantum: int) -> FairsharePlan:
+        """A whole fair-share pass in ONE device call (`_build_fairshare`)
+        — see `base.sequential_match_fairshare` for the reference
+        semantics this reproduces claim for claim.  Entries pad to a
+        power-of-two bucket (floor `_FS_ENTRY_LANES`), cohorts and
+        workers to `match`'s buckets."""
+        C, W = p.compat.shape
+        (order, req_o, d_o, crow_o, freeT, safe, big,
+         Cp, Wp) = self._prep(p)
+        nch = Cp // self.chunk
+        E = entries.n_entries
+        Ep = max(_FS_ENTRY_LANES, 1 << max(0, E - 1).bit_length())
+        self._fs_entry_buckets.add(Ep)
+        quantum = int(quantum)
+        cores_o = np.zeros(Cp)
+        cores_o[:C] = entries.cohort_cores[order[:C]]
+        self._require_exact("budget", quantum)
+        self._require_exact("cores per job", cores_o)
+        # the virtual ledgers never exceed the cores the demand can claim
+        self._require_exact("cores charged", float(cores_o @ d_o))
+        cent_o = np.full(Cp, -1, dtype=np.int32)
+        cent_o[:C] = entries.cohort_entry[order[:C]]
+        args = self._fairshare_args(
+            freeT, d_o, req_o, safe, big, crow_o, cent_o, cores_o,
+            entries, Ep, quantum, int(d_o.sum()) // quantum + E + 1)
+        rt = self._note_call("fairshare", ("fairshare", nch, Wp, Ep,
+                                           self.dtype))
+        with self._precision(), rt:
+            takes_j, freeT_j, n = self._fn_fairshare(
+                *(rt.put(x, d) for x, d in args))
+            takes_j = rt.get(takes_j)
+            freeT_j = rt.get(freeT_j, np.float64)
+            n = int(rt.get(n))
+        self.last_call["slices"] = n
+        takes = np.zeros((C, W), dtype=np.int64)
+        takes[order[:C]] = takes_j.reshape(Cp, Wp)[:C, :W]
+        return FairsharePlan(takes=takes, free_after=freeT_j[:, :W].T.copy(),
+                             slices=n, calls=1)
+
+    def _fairshare_args(self, freeT, d_o, req_o, safe, big, crow_o, cent_o,
+                        cores_o, entries, Ep, quantum, max_slices):
+        """The fair-share program's arguments as (host array, dtype)
+        pairs, in its order: one path for real calls and warm-ups, so
+        both trace the same program."""
+        dt = self.dtype
+        R, Wp = freeT.shape
+        nch = len(d_o) // self.chunk
+        E = entries.n_entries
+        ent_s = np.zeros(Ep, dtype=np.int32)
+        ent_s[:E] = entries.schedd
+        ent_u = np.zeros(Ep, dtype=np.int32)
+        ent_u[:E] = entries.user
+        live = np.arange(Ep) < E
+        by_chunk = (nch, self.chunk)
+        return (
+            (freeT, dt), (d_o.reshape(by_chunk), dt),
+            (req_o.reshape(nch, self.chunk, R), dt),
+            (safe.reshape(nch, self.chunk, R), dt),
+            (big.reshape(nch, self.chunk, R), dt),
+            (crow_o.reshape(nch, self.chunk, Wp), None),   # uint8 mask
+            (cent_o.reshape(by_chunk), np.int32),
+            (cores_o.reshape(by_chunk), dt),
+            (ent_s, np.int32), (ent_u, np.int32), (live, bool),
+            (key_terms(entries.owed, entries.owed_slope, dt, Ep), dt),
+            (key_terms(entries.prio, entries.prio_slope, dt, Ep), dt),
+            (np.asarray(quantum, dtype=np.float64), dt),
+            (np.asarray(max_slices, dtype=np.int32), np.int32))
+
+    def _warm_fairshare(self, nch: int, Wp: int, Ep: int):
+        """Compile the fair-share program for one bucket, on an empty
+        pass, unless a call has met the bucket already."""
+        bucket = ("fairshare", nch, Wp, Ep, self.dtype)
+        if bucket in self._seen_buckets:
+            return
+        self._seen_buckets.add(bucket)
+        R = len(RESOURCE_KEYS)
+        Cp = nch * self.chunk
+        none = np.zeros(0, dtype=np.int64)
+        entries = FairshareEntries(
+            cohort_entry=none, cohort_cores=np.zeros(0), schedd=none,
+            user=none, owed=np.zeros(0), owed_slope=np.zeros(0),
+            prio=np.zeros(0), prio_slope=np.zeros(0))
+        args = self._fairshare_args(
+            np.zeros((R, Wp)), np.zeros(Cp), np.zeros((Cp, R)),
+            np.ones((Cp, R)), np.zeros((Cp, R)),
+            np.zeros((Cp, Wp), dtype=np.uint8),
+            np.full(Cp, -1, dtype=np.int32), np.zeros(Cp), entries, Ep, 1, 1)
+        with self._precision():
+            out = self._fn_fairshare(
+                *(jnp.asarray(x, dtype=d) for x, d in args))
+            jax.block_until_ready(out)
 
     def preview_many(self, p: MatchProblem, frees: list,
                      demands: list | None = None, *,

@@ -42,7 +42,10 @@ quotas and per-user priority factors) builds N submit-host queues
 sharing one pool-unique jid counter, negotiated as ONE cycle in
 flocking order (`Collector.run_cycle`); `fairshare=True` (or an
 `Accountant`) adds hierarchical fair-share — per-schedd quotas, then
-per-user effective priority with usage decay.  The single-queue
+per-user effective priority with usage decay.  `fairshare=True` takes
+the terms of the config's `[fairshare]` section (half-life, base
+priority, default factor, quotas, factors, and the slice quantum), where
+it has one; a `ScheddSpec` passed in keeps its own quota.  The single-queue
 construction path is untouched (`sim.queue` keeps meaning the first/
 only schedd), matching the backend-adapter compat pattern.
 
@@ -113,7 +116,7 @@ class Simulation:
         engine: str = "event",
         schedds: int | list | None = None,
         fairshare: Accountant | bool | None = None,
-        negotiate_quantum: int = 1,
+        negotiate_quantum: int | None = None,
         matchmaker=None,
         negotiation_batch: int | None = None,
         telemetry=None,
@@ -132,6 +135,10 @@ class Simulation:
         # (or an Accountant) turns on hierarchical fair-share in the
         # negotiation cycle
         self.flocking = schedds is not None or fairshare is not None
+        fs_cfg = (getattr(cfg, "fairshare", None) if fairshare is True
+                  else None)
+        if negotiate_quantum is None:
+            negotiate_quantum = fs_cfg.quantum if fs_cfg is not None else 1
         self.negotiate_quantum = negotiate_quantum
         if fairshare and engine == "tick":
             # the tick engine's scan_cycle is the seed oracle and
@@ -143,11 +150,26 @@ class Simulation:
         if self.flocking:
             self.schedd_specs = make_schedd_specs(
                 schedds if schedds is not None else 1)
+            if fs_cfg is not None:
+                # a schedd named, not specified, takes the INI's quota
+                specified = ({s.name for s in schedds
+                              if isinstance(s, ScheddSpec)}
+                             if isinstance(schedds, (list, tuple)) else set())
+                self.schedd_specs = [
+                    s if s.name in specified else dataclasses.replace(
+                        s, quota=fs_cfg.quotas.get(s.name, s.quota))
+                    for s in self.schedd_specs]
             ids = itertools.count()
             self.queues = [JobQueue(name=s.name, ids=ids)
                            for s in self.schedd_specs]
             if fairshare is True:
-                fairshare = Accountant()
+                fairshare = (Accountant() if fs_cfg is None else Accountant(
+                    half_life_s=fs_cfg.half_life_s,
+                    base_priority=fs_cfg.base_priority,
+                    default_factor=fs_cfg.default_factor))
+                if fs_cfg is not None:
+                    for user, f in fs_cfg.factors.items():
+                        fairshare.set_priority_factor(user, f)
             self.accountant = fairshare or None
             if self.accountant is not None:
                 for spec, q in zip(self.schedd_specs, self.queues):
@@ -184,6 +206,12 @@ class Simulation:
         self.collector = Collector(matchmaker=matchmaker,
                                    negotiation_batch=negotiation_batch,
                                    telemetry=self.telemetry)
+        if (self.accountant is not None
+                and hasattr(self.collector.matchmaker, "fairshare_twins")):
+            # every pass is a fair-share pass: a backend that compiles
+            # per padding bucket compiles each `match` bucket's fair-share
+            # program beside it, so that warming `match` warms both
+            self.collector.matchmaker.fairshare_twins = True
         if backends is None:
             # single-backend compatibility adapter (seed signature)
             cluster = KubeCluster(nodes or [])

@@ -45,8 +45,9 @@ cycle over an ordered list of schedd queues feeding the same pool —
 capacity drains through a shared free matrix, plain mode serves queues
 strictly in flocking order, and with a fair-share `Accountant`
 (core/fairshare.py) the cycle water-fills capacity by per-schedd quota
-and per-user effective priority in quantum-sized `match(budget=...)`
-slices.  `preview()` is the claim-free dry run the provisioner
+and per-user effective priority in quantum-sized slices, the whole loop
+in one `match_fairshare` call (one device program on the jax backend).
+`preview()` is the claim-free dry run the provisioner
 subtracts from idle counts so it never provisions for jobs the next
 cycle will match anyway.  `negotiate`, `negotiate_scan`, and
 `preview_matches` remain as deprecated shims over the new entry points.
@@ -72,7 +73,8 @@ from repro.core.matchmaker import (
     MatchPlan, MatchProblem, Matchmaker, cohort_fits, make_matchmaker,
 )
 from repro.core.matchmaker.base import (
-    CycleDelta, match_cycles, sequential_preview_many,
+    CycleDelta, FairshareEntries, match_cycles, match_fairshare,
+    sequential_preview_many,
 )
 from repro.core.matchmaker.base import RESOURCE_KEYS  # noqa: F401
 from repro.observability import NO_SPAN, as_telemetry
@@ -703,7 +705,7 @@ class Collector:
             scan_order=scan_order)
 
     def _apply_plan(self, queues, problem: MatchProblem, plan: MatchPlan,
-                    workers, now: float, *, on_claim=None) -> int:
+                    workers, now: float) -> int:
         """Turn a pure plan into state: claim each cohort's FIFO jobs to
         its workers in index order.  Free capacity only shrinks within a
         cycle, so a cohort's first-fit worker index is non-decreasing —
@@ -725,8 +727,6 @@ class Collector:
                 for job in pending[ji:ji + int(row[wi])]:
                     q.claim(job.jid, w.name, now)
                     w.add_claim(job)
-                    if on_claim is not None:
-                        on_claim(job)
                     ji += 1
                 w.idle_since = -1.0
             claims += ji
@@ -1123,7 +1123,6 @@ class Collector:
             return 0
         reps = [next(iter(j.values())) for _qi, _k, j in rows]
         quantum = max(1, int(quantum))
-        total = 0
 
         if self._quantity_sensitive(reps, workers):
             # legacy per-claim ladder: identical water-fill, with the
@@ -1149,58 +1148,57 @@ class Collector:
             return total
 
         problem = self._build_problem(rows, workers)
-        t_b1 = prof.phase() if prof is not None else 0.0
-        match_s = apply_s = 0.0
-        group_rows: dict[tuple[int, str], list[int]] = {}
-        for c, g in enumerate(group_of):
-            group_rows.setdefault(g, []).append(c)
-        C = problem.n_cohorts
-        while group_rows:
-            si = min({i for i, _ in group_rows},
-                     key=lambda i: (accountant.group_owed(names[i], now),
-                                    i))
-            user = min((u for i, u in group_rows if i == si),
-                       key=lambda u: (
-                           accountant.effective_priority(u, now), u))
-            cores = [0.0]
-
-            def observe(job, _c=cores):
-                _c[0] += job_cores(job)
-
-            mask = np.zeros(C, dtype=bool)
-            mask[group_rows[(si, user)]] = True
-            t_s0 = prof.phase("repro.pass.match") if prof is not None else 0.0
-            plan = self.matchmaker.match(problem, budget=quantum,
-                                         active=mask)
-            t_s1 = prof.phase("repro.pass.apply") if prof is not None else 0.0
-            got = self._apply_plan(queues, problem, plan, workers, now,
-                                   on_claim=observe)
-            if prof is not None:
-                match_s += t_s1 - t_s0
-                apply_s += prof.phase() - t_s1
-                prof.note_device("cycle", getattr(self.matchmaker,
-                                                  "last_call", None))
-            problem.free = plan.free_after
-            problem.demand = problem.demand - plan.per_cohort()
-            if got:
-                accountant.charge_virtual(names[si], user, cores[0])
-                total += got
-            if got < quantum:
-                # demand or matching capacity exhausted for this user —
-                # neither can grow within the cycle, so retire the entry
-                del group_rows[(si, user)]
-        # claims are real running-core rates now; outside-the-cycle
-        # priority queries (metrics, owed-share deficits) must not see
-        # stale virtual charges on top of them
-        accountant.reset_cycle()
+        entries = self._fairshare_entries(group_of, reps, names, accountant,
+                                          now)
+        t_m0 = prof.phase("repro.pass.match") if prof is not None else 0.0
+        plan = match_fairshare(self.matchmaker, problem, entries, quantum)
+        t_a0 = prof.phase("repro.pass.apply") if prof is not None else 0.0
+        claims = self._apply_plan(queues, problem, plan, workers, now)
         if prof is not None:
             lc = getattr(self.matchmaker, "last_call", None)
+            prof.note_device("cycle", lc)
             prof.record_cycle(
                 t=now, kind="fairshare", w_start=t_c0,
-                build_s=t_b1 - t_c0, match_s=match_s, apply_s=apply_s,
-                claims=total, backend=getattr(self.matchmaker, "name", ""),
-                compiled=None if lc is None else lc.get("compiled"))
-        return total
+                build_s=t_m0 - t_c0, match_s=t_a0 - t_m0,
+                apply_s=prof.phase() - t_a0, claims=claims,
+                backend=getattr(self.matchmaker, "name", ""),
+                compiled=None if lc is None else lc.get("compiled"),
+                fairshare={
+                    "slices": plan.slices, "device_calls": plan.calls,
+                    "entries": entries.n_entries,
+                    "cohorts": int(np.count_nonzero(plan.takes.any(axis=1))),
+                    "workers": problem.n_workers,
+                    "resources": int(problem.requests.shape[1]),
+                    "nonzero_takes": int(np.count_nonzero(plan.takes))})
+        return claims
+
+    @staticmethod
+    def _fairshare_entries(group_of, reps, names, accountant,
+                           now: float) -> FairshareEntries:
+        """The pass's (schedd, user) entries over its cohort rows:
+        `group_of` gives each row's (schedd index, user).  Schedds are
+        numbered in flocking order and users in name order among the
+        pass's own; each key's value and rise per core come from the
+        accountant (`Accountant.pass_keys`)."""
+        entry_of: dict[tuple[int, str], int] = {}
+        for g in group_of:
+            entry_of.setdefault(g, len(entry_of))
+        schedds = sorted({si for si, _u in entry_of})
+        users = sorted({u for _si, u in entry_of})
+        s_rank = {si: i for i, si in enumerate(schedds)}
+        u_rank = {u: i for i, u in enumerate(users)}
+        owed, owed_slope, prio, prio_slope = accountant.pass_keys(
+            [names[si] for si in schedds], users, now)
+        return FairshareEntries(
+            cohort_entry=np.array([entry_of[g] for g in group_of],
+                                  dtype=np.int64),
+            cohort_cores=np.array([job_cores(r) for r in reps]),
+            schedd=np.array([s_rank[si] for si, _u in entry_of],
+                            dtype=np.int64),
+            user=np.array([u_rank[u] for _si, u in entry_of],
+                          dtype=np.int64),
+            owed=owed, owed_slope=owed_slope, prio=prio,
+            prio_slope=prio_slope)
 
     def _fairshare_ladder(self, queues, names, active, workers, free,
                           now, accountant, quantum, *, match) -> int:

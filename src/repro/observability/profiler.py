@@ -140,6 +140,14 @@ class CycleProfiler:
             "repro_device_transfer_bytes_total",
             "Bytes copied between host and device by matchmaker calls, "
             "by entry path and direction", ("path", "direction"))
+        self.fs_slices_c = registry.counter(
+            "repro_fairshare_slices_total",
+            "Fair-share slices negotiated (one (schedd, user) entry's "
+            "quantum-capped water-fill each)")
+        self.fs_calls_c = registry.counter(
+            "repro_fairshare_device_calls_total",
+            "Matchmaker calls fair-share passes made (one per slice on "
+            "the sequential path, one per pass on a fused one)")
         self._advance = advance_counters(registry)
         self.cycle_log_max = int(cycle_log_max)
         self.cycles: deque = deque(maxlen=self.cycle_log_max)
@@ -299,13 +307,18 @@ class CycleProfiler:
                      claims: int = 0, backend: str = "",
                      compiled: bool | None = None,
                      fused_k: int | None = None,
-                     fallback: str | None = None):
+                     fallback: str | None = None,
+                     fairshare: dict | None = None):
         """One negotiation cycle.  `w_start` is the absolute
         perf_counter at cycle start; durations are wall seconds.  The
         record also carries the open pass's `pass_id`, the round trip
         and bytes of the device calls since the pass's last record,
         `engine_s`, a snapshot of `engine_seconds()`, and beside it the
-        advancement counters `advance_touched` and `advance_calls`."""
+        advancement counters `advance_touched` and `advance_calls`.  A
+        fair-share pass's `fairshare` dict joins the record as it is:
+        `slices`, `device_calls`, `entries`, and the unpadded sizes its
+        roofline reads (`cohorts` that claimed, live `workers`,
+        `resources`, `nonzero_takes`)."""
         self.phase_h.labels("build").observe(build_s)
         self.phase_h.labels("match").observe(match_s)
         self.phase_h.labels("apply").observe(apply_s)
@@ -331,6 +344,10 @@ class CycleProfiler:
             rec["fused_k"] = fused_k
         if fallback is not None:
             rec["fallback"] = fallback
+        if fairshare is not None:
+            self.fs_slices_c.value += fairshare["slices"]
+            self.fs_calls_c.value += fairshare["device_calls"]
+            rec.update(fairshare)
         self.cycles.append(rec)
 
     def note_compile(self, path: str):

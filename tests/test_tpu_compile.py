@@ -6,7 +6,8 @@ and the CPU backend cannot: Mosaic's tiling and lowering rules for the
 Pallas water-fill, float64 leaking into the float32 path, and programs
 that do not fit the chip.  Shapes are the `bench_matchmaking` 1m tier
 (C=16,384 cohorts, W=1,024 workers) in float32, the dtype the backends
-pick on a TPU.
+pick on a TPU; the fair-share pass compiles at the `federation.fairshare`
+cell's bucket (128 cohorts, 640 workers, 32 entries).
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
@@ -23,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.core.matchmaker.jax_backend import (  # noqa: E402
-    _build_cycles_scan, _build_scan,
+    _build_cycles_scan, _build_fairshare, _build_scan,
 )
 from repro.kernels.waterfill.kernel import waterfill_pallas  # noqa: E402
 
@@ -89,3 +90,19 @@ def test_pallas_waterfill_compiles_for_v5e(one_chip):
     with jax.enable_x64(False):
         compiled = waterfill_pallas.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fairshare_pass_compiles_for_v5e(one_chip):
+    nch, wp, ep = 2, 640, 32
+    args = _shapes(one_chip,
+                   ((R, wp), F32), ((nch, CHUNK), F32),
+                   ((nch, CHUNK, R), F32), ((nch, CHUNK, R), F32),
+                   ((nch, CHUNK, R), F32), ((nch, CHUNK, wp), jnp.uint8),
+                   ((nch, CHUNK), jnp.int32), ((nch, CHUNK), F32),
+                   ((ep,), jnp.int32), ((ep,), jnp.int32), ((ep,), bool),
+                   ((5, ep), F32), ((5, ep), F32), ((), F32),
+                   ((), jnp.int32))
+    with jax.enable_x64(False):
+        compiled = _build_fairshare(CHUNK, UNROLL).lower(*args).compile()
+    text = compiled.as_text()
+    assert "while" in text and "f64" not in text
